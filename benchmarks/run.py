@@ -55,6 +55,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     from repro.kernels import registry
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     if args.kernels is None:
         modes = [registry.kernel_mode()]    # respect REPRO_KERNELS

@@ -21,6 +21,7 @@ import numpy as np
 from repro.core import (list_schedule, one_f_one_b_order, pipeline_tdg,
                         topo_waves)
 from repro.core.pipeline import bubble_fraction, pipeline_apply
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -37,8 +38,7 @@ def main():
     print(f"list-schedule makespan {sched.makespan:.0f} "
           f"(critical path bound: {len(waves)})")
 
-    mesh = jax.make_mesh((S,), ("stage",),
-                         devices=jax.devices()[:S])
+    mesh = make_mesh((S,), ("stage",), devices=jax.devices()[:S])
     d, mb = 32, 4
     key = jax.random.PRNGKey(0)
     Ws = jax.random.normal(key, (S, d, d)) * 0.3

@@ -96,23 +96,17 @@ def adaptive_enabled(arg: bool | str = "auto") -> bool:
 
 
 def capture_cost_analysis(compiled: Any) -> dict | None:
-    """Best-effort ``compiled.cost_analysis()`` -> plain dict, else None.
+    """``compiled.cost_analysis()`` as a plain dict, or None if there is none.
 
-    jax has returned ``[dict]``, ``dict`` and dict-likes across versions,
-    and backends without an analysis raise — every shape degrades to None
-    here rather than poisoning callers (also exported as
+    The installed jax returns a dict, and raises ``NotImplementedError`` on
+    a backend without an analysis (also exported as
     ``lower._capture_cost_analysis``).
     """
     try:
         ca = compiled.cost_analysis()
-    except Exception:
+    except NotImplementedError:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    try:
-        return dict(ca) if ca else None
-    except Exception:
-        return None
+    return dict(ca) if ca else None
 
 
 @dataclasses.dataclass(frozen=True)

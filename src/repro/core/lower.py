@@ -322,6 +322,9 @@ class AotExecutable:
     #: topology fingerprint so an 8-device binary is rejected loudly on a
     #: worker whose replay mesh differs.
     mesh_fp: str | None = None
+    #: The ``fuse.FusionPlan`` the trace applied (``None`` when unfused):
+    #: per-class batchers, pad lanes and any trace-time fallbacks.
+    plan: Any = None
 
     @property
     def flops(self) -> float | None:
@@ -386,4 +389,5 @@ def aot_compile_tdg(
                          donate_slots=donate_slots,
                          cost_analysis=_capture_cost_analysis(compiled),
                          trace_seconds=t1 - t0, compile_seconds=t2 - t1,
-                         mesh_fp=_shreplay.mesh_fingerprint(mesh))
+                         mesh_fp=_shreplay.mesh_fingerprint(mesh),
+                         plan=getattr(fn, "last_plan", None))

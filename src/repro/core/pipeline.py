@@ -72,13 +72,11 @@ def pipeline_apply(
             jnp.where(sid == S - 1, outs, jnp.zeros_like(outs)), axis)
         return outs[None]
 
-    from jax.experimental.shard_map import shard_map
-
     spec_p = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_p, P(None)),   # microbatches replicated
-                   out_specs=P(axis),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_p, P(None)),   # microbatches replicated
+                       out_specs=P(axis),
+                       check_vma=False)
     # feed every stage the full microbatch tensor; stage 0 uses it
     outs = fn(stage_params, x_microbatches)    # (S, M, mb, ...) stacked
     return outs[0]                             # identical post-broadcast

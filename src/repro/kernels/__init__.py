@@ -1,7 +1,7 @@
 """Pallas TPU kernels (with jnp oracles) for the performance-critical ops.
 
-``compat`` is the version-adaptive Pallas shim (one place absorbs upstream
-API renames); ``registry`` maps ``(op, backend, mode)`` to a substrate and
+``compat`` names the Pallas TPU spellings every kernel uses (one place to
+change on a jax upgrade); ``registry`` maps ``(op, backend, mode)`` to a substrate and
 owns the global kernel-mode switch; ``ops`` exposes the registry-dispatched
 public entry points used by models, executors, and benchmarks.
 """

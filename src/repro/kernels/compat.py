@@ -1,83 +1,27 @@
-"""Version-adaptive Pallas compatibility layer (the kernel substrate shim).
+"""The Pallas TPU spellings every kernel in this package goes through.
 
-JAX renames and reshuffles the Pallas TPU surface between releases: the TPU
-compiler-params class has been spelled both ``pltpu.TPUCompilerParams``
-(jax<=0.4.x) and ``pltpu.CompilerParams`` (newer), ``pl.pallas_call`` gains
-and loses optional keywords (``name``, ``cost_estimate``, ``backend``), and
-interpret mode has moved between a keyword and a context manager. Before this
-module existed, every kernel in this package hard-coded one spelling, so a
-single upstream rename broke all four kernels at once (32 red tests on
-jax 0.4.37).
+Each kernel builds its ``pallas_call``, compiler params and VMEM scratch
+through this module instead of importing ``jax.experimental.pallas.tpu``
+itself, so the Pallas surface the package depends on is named in one file:
 
-This shim centralizes every such decision behind feature detection —
-``getattr`` / signature inspection only, never version-string parsing — so
-the next rename is absorbed here, in one file:
+* :func:`tpu_compiler_params` builds ``pltpu.CompilerParams``;
+* :func:`pallas_call` is ``pl.pallas_call`` with the ``interpret=`` keyword
+  (the Pallas interpreter, how every kernel runs on the CPU);
+* :func:`vmem` allocates ``pltpu.VMEM`` scratch;
+* :func:`tpu_available` answers the registry's ``auto`` mode question.
 
-* :func:`tpu_compiler_params` builds the TPU compiler-params object under
-  whatever name this JAX exports, silently dropping hint fields the local
-  class does not know about (they are scheduling hints, never semantics).
-* :func:`pallas_call` wraps ``pl.pallas_call``, forwarding only the optional
-  keywords the installed signature accepts and resolving interpret-mode
-  execution (keyword if available, context-manager fallback otherwise).
-* :func:`vmem` allocates VMEM scratch under the local spelling.
-* :func:`interpret_supported` / :func:`tpu_available` answer capability
-  questions for the registry's mode resolution.
-
-Kernels in this package must not import ``jax.experimental.pallas.tpu``
-directly for anything this module provides; ``grep pltpu.CompilerParams``
-outside this file should stay empty. See ``docs/kernels.md`` for how this
-shim and the ``(op, backend, mode)`` registry fit together.
+Only the spellings of the installed JAX (``requirements.txt``) are kept;
+an unknown hint field is a ``TypeError`` at the call site. See
+``docs/kernels.md`` for how this module and the ``(op, backend, mode)``
+registry fit together.
 """
 from __future__ import annotations
 
-import dataclasses
-import inspect
 from typing import Any, Callable, Sequence
 
 import jax
 from jax.experimental import pallas as pl
-
-try:  # the TPU dialect may be absent on exotic builds; kernels then run
-    from jax.experimental.pallas import tpu as pltpu  # interpret-only.
-except Exception:  # pragma: no cover - import guard
-    pltpu = None  # type: ignore[assignment]
-
-
-def _first_attr(mod: Any, *names: str) -> Any:
-    """Return the first attribute of ``mod`` that exists, else None."""
-    if mod is None:
-        return None
-    for name in names:
-        obj = getattr(mod, name, None)
-        if obj is not None:
-            return obj
-    return None
-
-
-# Newer JAX spells it CompilerParams; 0.4.x spells it TPUCompilerParams.
-_COMPILER_PARAMS_CLS = _first_attr(pltpu, "CompilerParams", "TPUCompilerParams")
-_VMEM = _first_attr(pltpu, "VMEM")
-_FORCE_INTERPRET = _first_attr(pltpu, "force_tpu_interpret_mode")
-_PALLAS_CALL_PARAMS = frozenset(inspect.signature(pl.pallas_call).parameters)
-
-# Optional keywords that are pure hints: safe to drop when the installed
-# pallas_call does not accept them. Structural kwargs (grid, in_specs, ...)
-# are always forwarded so a genuinely incompatible JAX fails loudly.
-_DROPPABLE = ("compiler_params", "name", "cost_estimate", "backend", "debug")
-
-
-def _accepted_fields(cls: Any) -> frozenset[str]:
-    if dataclasses.is_dataclass(cls):
-        return frozenset(f.name for f in dataclasses.fields(cls))
-    try:
-        return frozenset(inspect.signature(cls).parameters)
-    except (TypeError, ValueError):  # pragma: no cover - exotic class
-        return frozenset()
-
-
-def has_tpu_compiler_params() -> bool:
-    """True if this JAX exports a TPU compiler-params class at all."""
-    return _COMPILER_PARAMS_CLS is not None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def tpu_available() -> bool:
@@ -85,45 +29,18 @@ def tpu_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def interpret_supported() -> bool:
-    """True if interpret-mode execution is reachable on this JAX."""
-    return "interpret" in _PALLAS_CALL_PARAMS or _FORCE_INTERPRET is not None
-
-
 def tpu_compiler_params(
     *, dimension_semantics: Sequence[str] | None = None, **hints: Any
-) -> Any:
-    """Build TPU compiler params under whatever name this JAX exports.
-
-    Returns an instance of ``pltpu.CompilerParams`` / ``pltpu.TPUCompilerParams``
-    (whichever exists), or ``None`` when the class is unavailable — the hints
-    only steer Mosaic scheduling, so omitting them is always semantically
-    safe. Hint fields the local class does not recognize are dropped rather
-    than raising, which is what lets one kernel source span JAX versions with
-    different hint vocabularies.
-    """
-    cls = _COMPILER_PARAMS_CLS
-    if cls is None:
-        return None
-    kw = dict(hints)
+) -> pltpu.CompilerParams:
+    """``pltpu.CompilerParams`` with the grid's dimension semantics."""
     if dimension_semantics is not None:
-        kw["dimension_semantics"] = tuple(dimension_semantics)
-    fields = _accepted_fields(cls)
-    if fields:
-        kw = {k: v for k, v in kw.items() if k in fields}
-    try:
-        return cls(**kw)
-    except TypeError:  # pragma: no cover - field-introspection miss
-        return None
+        hints["dimension_semantics"] = tuple(dimension_semantics)
+    return pltpu.CompilerParams(**hints)
 
 
 def vmem(shape: Sequence[int], dtype: Any) -> Any:
-    """Allocate a VMEM scratch shape under the local spelling."""
-    if _VMEM is None:  # pragma: no cover - TPU dialect absent
-        raise NotImplementedError(
-            "this JAX build exposes no pallas TPU VMEM scratch; kernels "
-            "needing scratch cannot run here (use the 'ref' substrate)")
-    return _VMEM(tuple(shape), dtype)
+    """A VMEM scratch buffer of ``shape`` and ``dtype``."""
+    return pltpu.VMEM(tuple(shape), dtype)
 
 
 def pallas_call(
@@ -132,31 +49,5 @@ def pallas_call(
     interpret: bool = False,
     **kwargs: Any,
 ) -> Callable[..., Any]:
-    """``pl.pallas_call`` with version differences resolved.
-
-    * optional hint kwargs (``compiler_params``, ``name``, ...) are forwarded
-      only when the installed signature accepts them, and skipped when None;
-    * ``interpret=True`` uses the keyword when available, else falls back to
-      the ``force_tpu_interpret_mode`` context manager, else raises a clear
-      error instead of a deep Mosaic lowering failure.
-    """
-    kw = dict(kwargs)
-    for key in _DROPPABLE:
-        if key in kw and (kw[key] is None or key not in _PALLAS_CALL_PARAMS):
-            del kw[key]
-
-    if "interpret" in _PALLAS_CALL_PARAMS:
-        return pl.pallas_call(kernel, interpret=interpret, **kw)
-    inner = pl.pallas_call(kernel, **kw)
-    if not interpret:
-        return inner
-    if _FORCE_INTERPRET is None:  # pragma: no cover - no interpret path
-        raise NotImplementedError(
-            "interpret-mode pallas execution is unavailable on this JAX "
-            "(no interpret= kwarg and no force_tpu_interpret_mode)")
-
-    def run_interpreted(*args: Any) -> Any:  # pragma: no cover - old JAX only
-        with _FORCE_INTERPRET():
-            return inner(*args)
-
-    return run_interpreted
+    """``pl.pallas_call``; ``interpret=True`` runs the body on the interpreter."""
+    return pl.pallas_call(kernel, interpret=interpret, **kwargs)

@@ -38,6 +38,18 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref):
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _divisor_block(dim: int, pref: int, lanes: int = 128) -> int:
+    """Largest lane multiple <= ``pref`` that divides ``dim``, else ``dim``.
+
+    A block must tile the operand exactly; a whole dimension is always a
+    legal block when no lane multiple divides it.
+    """
+    for b in range(min(pref, dim) // lanes * lanes, 0, -lanes):
+        if dim % b == 0:
+            return b
+    return dim
+
+
 def grouped_matmul(
     x: jax.Array,   # (E, C, d)
     w: jax.Array,   # (E, d, f)
@@ -50,9 +62,8 @@ def grouped_matmul(
     E, C, d = x.shape
     _, _, f = w.shape
     block_c = min(block_c, max(8, 1 << (C - 1).bit_length()))
-    block_d = min(block_d, d)
-    block_f = min(block_f, f)
-    assert d % block_d == 0 and f % block_f == 0, (d, f, block_d, block_f)
+    block_d = _divisor_block(d, block_d)
+    block_f = _divisor_block(f, block_f)
     c_pad = math.ceil(C / block_c) * block_c
     if c_pad != C:
         x = jnp.pad(x, ((0, 0), (0, c_pad - C), (0, 0)))

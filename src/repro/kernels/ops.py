@@ -4,8 +4,8 @@ Every public function here resolves its implementation through
 :mod:`repro.kernels.registry` — one table maps ``(op, backend, mode)`` to a
 substrate instead of per-function if/elif chains. The substrates:
 
-  * ``pallas``    — compiled Pallas kernels (TPU), built on the
-                    version-adaptive :mod:`repro.kernels.compat` shim;
+  * ``pallas``    — compiled Pallas kernels (TPU), built through
+                    :mod:`repro.kernels.compat`;
   * ``ref``       — memory-sane pure-XLA/jnp references (exact numerics,
                     the default on CPU);
   * ``interpret`` — the Pallas kernel bodies on the interpreter (CPU

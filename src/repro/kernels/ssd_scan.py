@@ -44,11 +44,18 @@ def _ssd_chunk_kernel(xs_ref, b_ref, c_ref, lda_ref,
     lda = lda_ref[0].astype(jnp.float32)          # (Q, 1)
     Q = xs.shape[0]
 
-    cums = jnp.cumsum(lda, axis=0)                # (Q, 1) inclusive
-    # decay(i<-j) = exp(cums[i] - cums[j]) for j <= i
-    diff = cums - cums.reshape(1, Q)              # (Q_i, Q_j)
     li = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    # Mosaic has no cumsum: the inclusive prefix sum is a masked reduction
+    # in f32 (exact adds, unlike a bf16-pass MXU matmul), taken once as a
+    # row and moved to a column through the diagonal (no relayout needed).
+    lda_b = jnp.broadcast_to(lda, (Q, Q))         # [i, j] = lda[i]
+    cums_row = jnp.sum(jnp.where(li <= lj, lda_b, 0.0), axis=0,
+                       keepdims=True)             # (1, Q) inclusive
+    cums = jnp.sum(jnp.where(li == lj, cums_row, 0.0), axis=1,
+                   keepdims=True)                 # (Q, 1) same values
+    # decay(i<-j) = exp(cums[i] - cums[j]) for j <= i
+    diff = cums - cums_row                        # (Q_i, Q_j)
     L = jnp.where(li >= lj, jnp.exp(diff), 0.0)
 
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),

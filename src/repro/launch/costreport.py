@@ -159,7 +159,7 @@ def _demo_tdgs() -> list[tuple[TDG, dict]]:
     st_tdg = TDG(region="demo_memory_bound")
     for i in range(8):
         st_tdg.add_task(relax, ins=[f"h{i}"], outs=[f"g{i}"])
-    st_bufs = {f"h{i}": jax.ShapeDtypeStruct((128, 128), f32)
+    st_bufs = {f"h{i}": jax.ShapeDtypeStruct((64, 64), f32)
                for i in range(8)}
 
     tiny_tdg = TDG(region="demo_below_breakeven")

@@ -8,6 +8,20 @@ module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis in ``AxisType.Auto`` mode.
+
+    The installed jax's ``jax.make_mesh`` defaults every axis to
+    ``Explicit`` sharding, under which an ambiguous gather (the sharded embedding
+    lookup, ``outs[i]`` after a ``shard_map``) raises ``ShardingTypeError``.
+    This repo's partition rules are written for GSPMD propagation, so every
+    mesh it builds goes through here.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -22,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def make_replay_mesh(n_devices: int | None = None,
@@ -42,7 +56,7 @@ def make_replay_mesh(n_devices: int | None = None,
             f"need {n} devices for the replay mesh, have {len(devices)} — "
             f"on CPU set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
             "before any jax import")
-    return jax.make_mesh((n,), (axis,), devices=devices[:n])
+    return make_mesh((n,), (axis,), devices=devices[:n])
 
 
 def make_small_mesh(n_data: int = 2, n_model: int = 2) -> jax.sharding.Mesh:
@@ -51,7 +65,7 @@ def make_small_mesh(n_data: int = 2, n_model: int = 2) -> jax.sharding.Mesh:
     devices = jax.devices()[:n]
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh((n_data, n_model), ("data", "model"), devices=devices)
+    return make_mesh((n_data, n_model), ("data", "model"), devices=devices)
 
 
 # TPU v5e per-chip hardware constants (roofline denominators)

@@ -44,8 +44,10 @@ Three modes:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import threading
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +56,14 @@ from ..configs import ARCHS, get_config, reduced
 from ..core.serialize import TaskFnRegistry
 from ..models import init_params, prefill
 from ..training import make_serve_step
+from . import compile_cache
+
+
+#: Weight init and prefill as one compiled program each (cfg and max_len
+#: static). Op by op, a full-width model dispatches every stacked weight's
+#: random draw, and every layer of the prompt pass, separately.
+init_params_jit = jax.jit(init_params, static_argnums=0)
+prefill_jit = jax.jit(prefill, static_argnums=(1, 3))
 
 
 def build_decode_registry(arch: str = "qwen2.5-3b",
@@ -84,7 +94,7 @@ def _run_single_stream(args, cfg, params) -> int:
 
     max_len = args.prompt_len + args.gen
     t0 = time.time()
-    logits, caches, pos = prefill(params, cfg, batch, max_len=max_len)
+    logits, caches, pos = prefill_jit(params, cfg, batch, max_len)
     jax.block_until_ready(logits)
     t_prefill = time.time() - t0
 
@@ -122,54 +132,84 @@ def _print_tier_latency(tiers_summary) -> None:
               f"p99 {s['p99_s']*1e3:.2f} ms")
 
 
-def _run_server(args, cfg, params) -> int:
+@dataclasses.dataclass
+class ServedDecode:
+    """What :func:`serve_decode` returns: prompts in, tokens out, metrics."""
+
+    prompts: list          # per tenant: (batch, prompt_len) int32
+    tokens: list           # per tenant: (batch, gen) int32, first from prefill
+    kernel_modes: list     # per tenant: the substrate the server pinned
+    stats: dict            # RegionServer.stats() after close
+    prefill_s: float
+    decode_s: float
+    server: Any            # the closed RegionServer (trace ring, pool)
+
+
+#: A served step may include compiling the batched decode program: a
+#: full-width model's takes tens of seconds on the chip.
+_STEP_TIMEOUT_S = 600.0
+
+
+def serve_decode(cfg, params, *, tenants: int, batch: int, prompt_len: int,
+                 gen: int, seed: int = 0, max_batch: int = 0,
+                 max_wait_ms: float = 5.0, request_level: bool = False,
+                 tiers: list[int] | None = None,
+                 tenant_rate: float = 0.0) -> ServedDecode:
+    """Serve ``tenants`` decode streams through one ``RegionServer``.
+
+    Each tenant prefills its own seeded prompt (private caches and
+    positions; ``params`` is the same object for all, so a coalesced batch
+    broadcasts rather than stacks it), registers a one-task decode-step
+    region, and then issues ``gen - 1`` decode steps from its own thread.
+    """
     from ..core import TDG
     from ..serving import RegionServer
 
     decode = make_serve_step(cfg)
-    max_len = args.prompt_len + args.gen
+    max_len = prompt_len + gen
 
-    # Per-tenant prefill: private prompt, caches and positions; params are
-    # shared (same object), so the server broadcasts rather than stacks them
-    # in a coalesced batch.
     states = []
     t0 = time.time()
-    for i in range(args.tenants):
-        key = jax.random.PRNGKey(args.seed + 1 + i)
-        batch = {"tokens": jax.random.randint(
-            key, (args.batch, args.prompt_len), 2, cfg.vocab_size)}
+    for i in range(tenants):
+        key = jax.random.PRNGKey(seed + 1 + i)
+        batch_in = {"tokens": jax.random.randint(
+            key, (batch, prompt_len), 2, cfg.vocab_size)}
         if cfg.family == "encdec":
-            batch["frames"] = jax.random.normal(
-                key, (args.batch, cfg.encoder_seq, cfg.d_model), jnp.float32)
-        logits, caches, pos = prefill(params, cfg, batch, max_len=max_len)
+            batch_in["frames"] = jax.random.normal(
+                key, (batch, cfg.encoder_seq, cfg.d_model), jnp.float32)
+        logits, caches, pos = prefill_jit(params, cfg, batch_in, max_len)
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        states.append({"tok": tok, "pos": pos, "caches": caches, "out": [tok]})
+        states.append({"prompt": batch_in["tokens"], "tok": tok, "pos": pos,
+                       "caches": caches, "out": [tok]})
     jax.block_until_ready([s["tok"] for s in states])
     t_prefill = time.time() - t0
 
-    server = RegionServer(max_batch=args.max_batch or args.tenants,
-                          max_wait_ms=args.max_wait_ms, name="decode-server",
-                          continuous=False if args.request_level else None)
-    tiers = _tenant_tiers(args)
-    for i in range(args.tenants):
+    server = RegionServer(max_batch=max_batch or tenants,
+                          max_wait_ms=max_wait_ms, name="decode-server",
+                          continuous=False if request_level else None)
+    tiers = tiers or [0] * tenants
+    modes = []
+    for i in range(tenants):
         # One decode-step region per tenant — structurally identical across
         # tenants (same payload object), so they intern to one executable.
         tdg = TDG(f"decode[{i}]")
         tdg.add_task(decode, ins=["params", "tokens", "pos", "caches"],
                      outs=["next", "caches"], name="decode")
-        server.register_tenant(f"tenant{i}", tdg, outputs=("next", "caches"),
-                               tier=tiers[i],
-                               rate=args.tenant_rate or None)
+        t = server.register_tenant(f"tenant{i}", tdg,
+                                   outputs=("next", "caches"),
+                                   tier=tiers[i], rate=tenant_rate or None)
+        modes.append(t.kernel_mode)
 
     errors: list[BaseException] = []
 
     def tenant_loop(i: int) -> None:
         try:
             st = states[i]
-            for _ in range(args.gen - 1):
+            for _ in range(gen - 1):
                 out = server.serve(f"tenant{i}", {
                     "params": params, "tokens": st["tok"][:, None],
-                    "pos": st["pos"], "caches": st["caches"]})
+                    "pos": st["pos"], "caches": st["caches"]},
+                    timeout=_STEP_TIMEOUT_S)
                 st["tok"] = out["next"]
                 st["caches"] = out["caches"]
                 st["pos"] = st["pos"] + 1
@@ -178,7 +218,7 @@ def _run_server(args, cfg, params) -> int:
             errors.append(e)
 
     threads = [threading.Thread(target=tenant_loop, args=(i,))
-               for i in range(args.tenants)]
+               for i in range(tenants)]
     t0 = time.time()
     for t in threads:
         t.start()
@@ -188,14 +228,28 @@ def _run_server(args, cfg, params) -> int:
     server.close()
     if errors:
         raise errors[0]
+    return ServedDecode(
+        prompts=[s["prompt"] for s in states],
+        tokens=[jnp.stack(s["out"], axis=1) for s in states],
+        kernel_modes=modes, stats=server.stats(), prefill_s=t_prefill,
+        decode_s=t_decode, server=server)
 
-    stats = server.stats()
+
+def _run_server(args, cfg, params) -> int:
+    res = serve_decode(cfg, params, tenants=args.tenants, batch=args.batch,
+                       prompt_len=args.prompt_len, gen=args.gen,
+                       seed=args.seed, max_batch=args.max_batch,
+                       max_wait_ms=args.max_wait_ms,
+                       request_level=args.request_level,
+                       tiers=_tenant_tiers(args),
+                       tenant_rate=args.tenant_rate)
+    stats = res.stats
     m = stats["metrics"]
     toks = args.tenants * args.batch * (args.gen - 1)
-    print(f"prefill: {t_prefill*1e3:.1f} ms for {args.tenants} tenants "
+    print(f"prefill: {res.prefill_s*1e3:.1f} ms for {args.tenants} tenants "
           f"x {args.batch}x{args.prompt_len}")
-    print(f"decode:  {t_decode*1e3:.1f} ms for {args.gen-1} steps x "
-          f"{args.tenants} tenants ({toks / max(t_decode, 1e-9):.1f} tok/s)")
+    print(f"decode:  {res.decode_s*1e3:.1f} ms for {args.gen-1} steps x "
+          f"{args.tenants} tenants ({toks / max(res.decode_s, 1e-9):.1f} tok/s)")
     print(f"server:  {m['batches']} batches, occupancy mean "
           f"{m['batch_occupancy_mean']:.2f} max {m['batch_occupancy_max']}, "
           f"{m['batch_fallbacks']} fallbacks, queue peak "
@@ -206,11 +260,10 @@ def _run_server(args, cfg, params) -> int:
     _print_tier_latency(m.get("tiers"))
     print(f"trace:   {m['trace']}")
     if args.trace_out:
-        server.dump_trace(args.trace_out)
+        res.server.dump_trace(args.trace_out)
         print(f"trace ring written to {args.trace_out}")
     for i in (0, args.tenants - 1):
-        gen = jnp.stack(states[i]["out"], axis=1)
-        print(f"tenant{i} sample token ids:", gen[0, :12].tolist())
+        print(f"tenant{i} sample token ids:", res.tokens[i][0, :12].tolist())
     return 0
 
 
@@ -231,7 +284,7 @@ def _run_cluster(args, cfg, params) -> int:
         if cfg.family == "encdec":
             batch["frames"] = jax.random.normal(
                 key, (args.batch, cfg.encoder_seq, cfg.d_model), jnp.float32)
-        logits, caches, pos = prefill(params, cfg, batch, max_len=max_len)
+        logits, caches, pos = prefill_jit(params, cfg, batch, max_len)
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         states.append({"tok": tok, "pos": pos, "caches": caches, "out": [tok]})
     jax.block_until_ready([s["tok"] for s in states])
@@ -319,6 +372,16 @@ def _run_cluster(args, cfg, params) -> int:
     return 0
 
 
+def _spawns_local_workers(args) -> bool:
+    """Would this ``--cluster``/``--workers`` run spawn workers here?"""
+    from ..serving.spawner import parse_worker_spec
+
+    if not args.workers:
+        return True
+    return any(parse_worker_spec(w) is None
+               for w in args.workers.split(",") if w.strip())
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCHS), default="qwen2.5-3b")
@@ -360,13 +423,24 @@ def main(argv=None):
                     help="[--server/--cluster] dump the execution-pattern "
                          "trace ring(s) to PATH as JSON after the run")
     args = ap.parse_args(argv)
+    compile_cache.enable()
+
+    cluster = args.cluster is not None or bool(args.workers)
+    if cluster and _spawns_local_workers(args) \
+            and jax.default_backend() == "tpu":
+        raise SystemExit(
+            "--cluster cannot spawn local workers on a TPU host: a chip "
+            "belongs to one process at a time, and this frontend process "
+            "already holds it, so every local worker would fail to start. "
+            "Run one `python -m repro.serving.worker` per chip host and pass "
+            "their addresses with --workers host:port,...")
 
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduced(cfg)
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    params = init_params_jit(cfg, jax.random.PRNGKey(args.seed))
 
-    if args.cluster is not None or args.workers:
+    if cluster:
         return _run_cluster(args, cfg, params)
     if args.server:
         return _run_server(args, cfg, params)

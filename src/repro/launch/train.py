@@ -25,6 +25,7 @@ from ..optim import adamw, warmup_cosine, wsd
 from ..runtime import RunState, StragglerPolicy, run_with_recovery
 from ..sharding import partition as P_
 from ..training import make_train_step
+from . import compile_cache
 from .mesh import make_small_mesh
 
 
@@ -58,6 +59,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg, optimizer = build(args.arch, args.smoke, args.seq, args.batch,
                            args.steps, args.lr, args.schedule)

@@ -141,7 +141,6 @@ def moe_apply_shard_map(p: Params, cfg: ModelConfig, x: jax.Array
     root-task distribution applied to experts: placement decided once by the
     sharding, no runtime negotiation.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = P_.active_mesh()
@@ -213,7 +212,7 @@ def moe_apply_shard_map(p: Params, cfg: ModelConfig, x: jax.Array
         out = jax.lax.psum(combined, "model")             # join over experts
         return out.reshape(Bl, S, d).astype(cdt), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_spec, None, None),              # x: batch-sharded
                   P(None, None),                          # router replicated
@@ -221,7 +220,7 @@ def moe_apply_shard_map(p: Params, cfg: ModelConfig, x: jax.Array
                   P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(batch_spec, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     out, aux = fn(x, p["router"]["w"],
                   p["experts"]["up"]["w"], p["experts"]["gate"]["w"],
                   p["experts"]["down"]["w"])

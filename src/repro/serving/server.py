@@ -70,7 +70,7 @@ import os
 import threading
 import time
 from concurrent.futures import Future
-from typing import Any, Callable, Mapping
+from typing import Any, Callable, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -1107,15 +1107,53 @@ class RegionServer:
                     results.append(exc)
             return results, False
 
-    def _run_batched_fused(self, group: list[_Request]) -> list[dict]:
-        tenant0 = group[0].tenant
+    def _split_shared(self, group: list[_Request]) -> tuple[dict, tuple]:
+        """(buffers every member shares by identity, varying slot names)."""
         canon = [r.canon_buffers for r in group]
         slots = sorted(canon[0])
         shared = frozenset(
             s for s in slots
             if all(cb[s] is canon[0][s] for cb in canon[1:]))
-        varying = tuple(s for s in slots if s not in shared)
-        shared_bufs = {s: canon[0][s] for s in shared}
+        return ({s: canon[0][s] for s in shared},
+                tuple(s for s in slots if s not in shared))
+
+    def _batched_entry(self, tenant0: Tenant, shared: frozenset) -> PoolEntry:
+        key = ("batched", tenant0.sig, tenant0.payload_ids, shared,
+               tenant0.kernel_mode, self.mesh_fp)
+        entry = self.pool.get(key)
+        if entry is None:
+            entry = self.pool.put(key, PoolEntry(
+                "batched", self._build_batched(tenant0), tenant0.payloads))
+        return entry
+
+    def compile_batched(self, name: str,
+                        members: Sequence[Mapping[str, Any]]) -> Any:
+        """The pooled batched program compiled as it serves ``members``.
+
+        ``members`` are request buffers of tenant ``name`` (arrays or
+        ``ShapeDtypeStruct`` specs under the tenant's slot names), one per
+        batch member; a buffer passed as the same object to every member is
+        shared, as in a coalesced step. The pooled ``"batched"`` callable is
+        lowered at the occupancy bucket the server would pick and compiled,
+        so the caller reads the program the server runs (its HLO, its
+        memory). The bucket tuner is not fed.
+        """
+        group = [self._make_request(name, b) for b in members]
+        tenant0 = group[0].tenant
+        shared_bufs, varying = self._split_shared(group)
+        if not varying:
+            raise ValueError("every buffer is shared: the server replays one "
+                             "member instead of a batched program")
+        entry = self._batched_entry(tenant0, frozenset(shared_bufs))
+        per_req = [{s: r.canon_buffers[s] for s in varying} for r in group]
+        per_req.extend(per_req[-1:] * self._bucket_and_pad(len(per_req))[1])
+        with _kreg.kernel_mode_scope(tenant0.kernel_mode):
+            return entry.fn.lower(tuple(per_req), shared_bufs).compile()
+
+    def _run_batched_fused(self, group: list[_Request]) -> list[dict]:
+        tenant0 = group[0].tenant
+        canon = [r.canon_buffers for r in group]
+        shared_bufs, varying = self._split_shared(group)
         if not varying:
             # Every buffer is literally shared: one single-request replay
             # serves the whole batch (all members compute the same values).
@@ -1124,12 +1162,7 @@ class RegionServer:
                          for s, v in out0.items()}
             return [{r.tenant.from_canon[c]: v for c, v in canon_out.items()}
                     for r in group]
-        key = ("batched", tenant0.sig, tenant0.payload_ids, shared,
-               tenant0.kernel_mode, self.mesh_fp)
-        entry = self.pool.get(key)
-        if entry is None:
-            entry = self.pool.put(key, PoolEntry(
-                "batched", self._build_batched(tenant0), tenant0.payloads))
+        entry = self._batched_entry(tenant0, frozenset(shared_bufs))
         # Bucket occupancy (padding with a repeat of the last member,
         # dropped after the call): jit specializes the batched program per
         # pytree arity, so without bucketing every straggler-induced

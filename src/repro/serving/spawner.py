@@ -128,8 +128,19 @@ class SpawnError(RuntimeError):
 
 
 def _worker_main(port_conn, registry_spec, registry_kwargs, server_kwargs,
-                 token) -> None:
-    """Spawned-process entry point: build the node, report the port, serve."""
+                 token, platform) -> None:
+    """Spawned-process entry point: build the node, report the port, serve.
+
+    The worker takes the parent's JAX platform or dies: with the platform
+    pinned, a child that cannot get it (a TPU chip already held by the
+    parent) raises here instead of quietly serving from the CPU, and the
+    parent's :meth:`LocalSpawner.connect` turns the exit into a
+    :class:`SpawnError`.
+    """
+    import jax
+
+    jax.config.update("jax_platforms", platform)
+    jax.devices()
     # Deferred import: this body runs in the child process; importing
     # cluster at module scope here would cycle (cluster imports spawner).
     from .cluster import WorkerNode, resolve_registry
@@ -149,7 +160,8 @@ class LocalSpawner:
     Two-phase on purpose: :meth:`launch` starts the process and returns
     immediately so a frontend can overlap N cold starts (a fresh
     interpreter + jax import is seconds each); :meth:`connect` then waits
-    for the reported port, TCP-connects and handshakes.
+    for the reported port, TCP-connects and handshakes. Every worker runs
+    on this process's JAX platform (``jax.default_backend()``).
     """
 
     def __init__(self, registry_spec: str,
@@ -169,6 +181,9 @@ class LocalSpawner:
         self.transport = rpc.transport_mode(transport)
         self.shm_bytes = shm_bytes
         self._ctx = multiprocessing.get_context(start_method)
+        import jax
+
+        self.platform = jax.default_backend()
 
     def launch(self, idx: int, name: str) -> tuple:
         if _faults.ENABLED:
@@ -180,7 +195,7 @@ class LocalSpawner:
         proc = self._ctx.Process(
             target=_worker_main,
             args=(child_conn, self.registry_spec, self.registry_kwargs,
-                  self.server_kwargs, self.token),
+                  self.server_kwargs, self.token, self.platform),
             name=name, daemon=True)
         proc.start()
         child_conn.close()
@@ -189,11 +204,19 @@ class LocalSpawner:
     def connect(self, pending: tuple, timeout: float,
                 force_tcp: bool = False) -> SpawnedWorker:
         idx, proc, parent_conn = pending
-        if not parent_conn.poll(timeout):
-            raise SpawnError(f"worker {idx} did not report its RPC port "
-                             f"within {timeout}s")
-        port = parent_conn.recv()
-        parent_conn.close()
+        try:
+            if not parent_conn.poll(timeout):
+                raise SpawnError(f"worker {idx} did not report its RPC port "
+                                 f"within {timeout}s")
+            port = parent_conn.recv()
+        except EOFError:
+            proc.join(timeout=5.0)
+            raise SpawnError(
+                f"worker {idx} exited (code {proc.exitcode}) before "
+                f"reporting its RPC port (a worker runs on this process's "
+                f"JAX platform, {self.platform!r}, or not at all)") from None
+        finally:
+            parent_conn.close()
         conn = rpc.connect("127.0.0.1", port, timeout=timeout)
         would_shm = self.transport in ("shm", "auto")
         try:

@@ -211,3 +211,35 @@ class TestExecutableSerialization:
             f.write("{not json")
         with pytest.raises(Exception):
             load_warm(path, REG)
+
+
+class TestCompileCacheDir:
+    """Entry points keep JAX's persistent cache at one fixed path."""
+
+    @pytest.fixture()
+    def cache_dir_config(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_var_wins_and_nothing_is_set(self, monkeypatch,
+                                             cache_dir_config):
+        from repro.launch import compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_path_in_checkout(self, monkeypatch,
+                                               cache_dir_config):
+        import pathlib
+
+        from repro.launch import compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        want = str(root / ".jax_cache")
+        assert compile_cache.enable() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert compile_cache.enable() == want          # same path every call

@@ -19,6 +19,7 @@ import struct
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -911,6 +912,30 @@ class TestWorkerSpecParsing:
     def test_bad_specs_fail_at_construction(self, bad):
         with pytest.raises(ValueError, match="worker spec"):
             parse_worker_spec(bad)
+
+
+class TestLocalSpawnPlatform:
+    def test_worker_without_parent_platform_fails_at_spawn(self, monkeypatch):
+        from repro.serving.spawner import LocalSpawner, SpawnError
+
+        # A parent on a platform the child cannot get (as on a TPU host
+        # whose one chip the parent holds): the child must die at spawn,
+        # never fall back to serving from the CPU.
+        monkeypatch.setattr(jax, "default_backend", lambda: "cuda")
+        spawner = LocalSpawner("repro.launch.serve:build_decode_registry",
+                               None, None, token=None)
+        assert spawner.platform == "cuda"
+        pending = spawner.launch(0, "platform-probe")
+        proc = pending[1]
+        try:
+            with pytest.raises(SpawnError, match="exited"):
+                spawner.connect(pending, timeout=120)
+            proc.join(timeout=10)
+            assert not proc.is_alive() and proc.exitcode != 0
+        finally:
+            if proc.is_alive():
+                proc.kill()
+                proc.join(timeout=10)
 
 
 # ---------------------------------------------------------------------------

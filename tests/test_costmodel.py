@@ -2,8 +2,8 @@
 
 The invariants that make adaptivity safe to ship:
 
-* every ``cost_analysis`` shape jax has ever returned (and every failure)
-  degrades to None / UNMEASURED — never an exception, never a lie;
+* a backend without a ``cost_analysis`` degrades to None / UNMEASURED,
+  never a lie;
 * the decision matrix is exactly the documented policy, and an unmeasured
   payload always falls back to the static vmap plan;
 * different batcher *plans* never share an interned executable, while the
@@ -40,7 +40,7 @@ class _Compiled:
 
     def cost_analysis(self):
         if self._raises:
-            raise RuntimeError("no analysis on this backend")
+            raise self._raises("no analysis on this backend")
         return self._result
 
 
@@ -51,7 +51,8 @@ class TestCaptureCostAnalysis:
         assert lower_mod._capture_cost_analysis is cm.capture_cost_analysis
 
     def test_raising_backend_degrades_to_none(self):
-        assert cm.capture_cost_analysis(_Compiled(raises=True)) is None
+        assert cm.capture_cost_analysis(
+            _Compiled(raises=NotImplementedError)) is None
 
     def test_none_and_empty_shapes_degrade_to_none(self):
         assert cm.capture_cost_analysis(_Compiled(None)) is None
@@ -59,9 +60,12 @@ class TestCaptureCostAnalysis:
         assert cm.capture_cost_analysis(_Compiled(())) is None
         assert cm.capture_cost_analysis(_Compiled({})) is None
 
-    def test_list_of_dict_unwraps(self):
-        got = cm.capture_cost_analysis(_Compiled([{"flops": 8.0}]))
-        assert got == {"flops": 8.0}
+    def test_real_compiled_executable(self):
+        compiled = jax.jit(lambda a: a @ a).lower(
+            jax.ShapeDtypeStruct((32, 32), f32)).compile()
+        got = cm.capture_cost_analysis(compiled)
+        assert type(got) is dict
+        assert got["flops"] > 0 and got["bytes accessed"] > 0
 
     def test_plain_dict_passes_through(self):
         got = cm.capture_cost_analysis(_Compiled({"bytes accessed": 64.0}))
@@ -71,8 +75,9 @@ class TestCaptureCostAnalysis:
         ca = collections.OrderedDict(flops=2.0)
         assert cm.capture_cost_analysis(_Compiled(ca)) == {"flops": 2.0}
 
-    def test_unconvertible_degrades_to_none(self):
-        assert cm.capture_cost_analysis(_Compiled(object())) is None
+    def test_other_errors_propagate(self):
+        with pytest.raises(RuntimeError):
+            cm.capture_cost_analysis(_Compiled(raises=RuntimeError))
 
 
 # --------------------------------------------------------- decision matrix
@@ -257,7 +262,9 @@ def _mixed_tdg():
     bufs = {}
     for i in range(4):
         bufs[f"a{i}"] = jnp.asarray(rng.standard_normal((64, 64)), f32)
-        bufs[f"h{i}"] = jnp.asarray(rng.standard_normal((128, 128)), f32)
+        # 64x64 stencil: XLA counts ~80 KB per member (cache-resident) and
+        # ~320 KB for the class of 4, which is the lax.map band.
+        bufs[f"h{i}"] = jnp.asarray(rng.standard_normal((64, 64)), f32)
         bufs[f"s{i}"] = jnp.asarray(rng.standard_normal((2,)), f32)
     bufs["w"] = jnp.asarray(rng.standard_normal((64, 64)), f32)
     return tdg, bufs

@@ -23,8 +23,9 @@ def _run(code: str, devices: int = 4, timeout: int = 420):
 def test_pipeline_parallel_forward_backward():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.core.pipeline import pipeline_apply
-mesh = jax.make_mesh((4,), ("stage",), devices=jax.devices()[:4])
+mesh = make_mesh((4,), ("stage",), devices=jax.devices()[:4])
 S, M, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 Ws = jax.random.normal(key, (S, d, d)) * 0.3
@@ -48,11 +49,12 @@ def test_moe_shard_map_equals_gspmd():
     _run("""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config, reduced
 from repro.models import moe as MoE
 from repro.sharding import partition as P_
 from jax.sharding import NamedSharding, PartitionSpec as P
-mesh = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 cfg = reduced(get_config("qwen3-moe-30b-a3b"))
 key = jax.random.PRNGKey(0)
 p = MoE.moe_init(key, cfg)
@@ -72,13 +74,14 @@ print("OK")
 def test_train_step_on_2x2_mesh():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config, reduced
 from repro.models import init_params
 from repro.optim import adamw
 from repro.sharding import partition as P_
 from repro.training import make_train_step
 from jax.sharding import NamedSharding, PartitionSpec as P
-mesh = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 cfg = reduced(get_config("glm4-9b"), d_model=64, num_heads=4, head_dim=16)
 opt = adamw(1e-3)
 with P_.use_mesh(mesh):
@@ -103,7 +106,8 @@ def test_dryrun_single_cell_small_mesh():
     _run("""
 import jax
 from repro.launch.dryrun import lower_cell
-mesh = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 r = lower_cell("qwen2.5-3b", "train_4k", mesh=mesh, save=False)
 assert r["roofline"]["hlo_flops_per_device"] > 0
 assert r["cost_mode"] == "extrapolated_exact"

@@ -130,26 +130,32 @@ class TestRegistrySemantics:
 # -------------------------------------------------------------------- compat
 
 class TestCompat:
-    def test_compiler_params_resolved_by_feature_detection(self):
-        params = compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"))
-        if compat.has_tpu_compiler_params():
-            assert params is not None
-            assert tuple(params.dimension_semantics) == ("parallel",
-                                                         "arbitrary")
-        else:
-            assert params is None
+    def test_compiler_params(self):
+        from jax.experimental.pallas import tpu as pltpu
 
-    def test_unknown_hint_fields_are_dropped(self):
         params = compat.tpu_compiler_params(
-            dimension_semantics=("parallel",),
-            definitely_not_a_real_hint_field_xyz=1)
-        if compat.has_tpu_compiler_params():
-            assert not hasattr(params, "definitely_not_a_real_hint_field_xyz")
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=1 << 20)
+        assert isinstance(params, pltpu.CompilerParams)
+        assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
+        assert params.vmem_limit_bytes == 1 << 20
+
+    def test_unknown_hint_field_raises(self):
+        # Nothing is dropped silently any more: a hint field that
+        # pltpu.CompilerParams does not have fails where it is passed.
+        with pytest.raises(TypeError):
+            compat.tpu_compiler_params(
+                dimension_semantics=("parallel",),
+                definitely_not_a_real_hint_field_xyz=1)
 
     def test_interpret_supported_here(self):
-        # this repo's CPU CI depends on interpret mode existing
-        assert compat.interpret_supported()
+        # this repo's CPU CI runs every kernel through pallas_call's
+        # interpret= keyword
+        import inspect
+
+        from jax.experimental import pallas as pl
+
+        assert "interpret" in inspect.signature(pl.pallas_call).parameters
 
     def test_pallas_call_interpret_smoke(self):
         def kernel(x_ref, o_ref):

@@ -150,6 +150,26 @@ class TestCoalescing:
         assert pool["hits"] >= 2            # ... reused by later batches
         assert server.metrics.snapshot()["batches"] == 3
 
+    def test_compile_batched_is_the_served_program(self):
+        # What compile_batched hands back is the pooled entry a coalesced
+        # step then runs: 3 members in the 4-lane bucket, w broadcast.
+        n = 3
+        w = jnp.eye(6, dtype=jnp.float32)
+        server = RegionServer(max_batch=4, max_wait_ms=500, autostart=False)
+        for i in range(n):
+            server.register_tenant(f"t{i}", _region(i))
+        compiled = server.compile_batched(
+            "t0", [_bufs(60 + i, shared_w=w) for i in range(n)])
+        assert "f32[4,6,6]" in compiled.as_text()
+        futs = [server.submit(f"t{i}", _bufs(70 + i, shared_w=w))
+                for i in range(n)]
+        server.start()
+        for f in futs:
+            f.result(120)
+        server.close()
+        pool = server.pool.stats()
+        assert (pool["misses"], pool["hits"]) == (1, 1)
+
     def test_shared_buffer_broadcast_not_stacked(self):
         # All members pass the SAME w object: results must still be exact
         # per-tenant (their private x slots differ).

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.sharding import partition as P_
 
 pytestmark = pytest.mark.skipif(
@@ -14,8 +15,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    return make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
 
 
 class TestPartitionRules:
@@ -45,8 +45,7 @@ class TestPartitionRules:
         assert len(flat) == len(set(flat))   # each mesh axis used once
 
     def test_sanitize_drops_nondivisible(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"),
-                             devices=jax.devices()[:1])
+        mesh = _mesh11()
         spec = P_.sanitize_spec((7, 64), P("model", "data"), mesh)
         assert spec == P("model", "data")   # axis size 1 divides everything
 
