@@ -1,0 +1,8 @@
+"""Puts the checkout and its ``src`` on the path for the benchmark's tests."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
