@@ -61,6 +61,8 @@ import os
 import threading
 from typing import Any, Callable, Iterable, Mapping, Sequence
 
+from . import spans as _spans
+
 ADAPTIVE_ENV = "REPRO_ADAPTIVE"
 
 #: Arithmetic-intensity ridge (flops/byte) separating compute-bound from
@@ -217,8 +219,11 @@ class CostModel:
         import jax
 
         self.probes += 1
+        _spans.count("taskgraph.costmodel.probes")
         try:
-            compiled = jax.jit(fn).lower(*arg_specs).compile()
+            with _spans.span("taskgraph.costmodel.probe",
+                             payload=getattr(fn, "__name__", "?")):
+                compiled = jax.jit(fn).lower(*arg_specs).compile()
         except Exception:
             self.probe_failures += 1
             return UNMEASURED

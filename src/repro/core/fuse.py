@@ -264,7 +264,12 @@ def _bind_outs(task, out, env: dict) -> None:
             env[s] = v
 
 
-def _run_unrolled(tdg: TDG, tids: Sequence[int], env: dict) -> None:
+def _run_unrolled(tdg: TDG, tids: Sequence[int], env: dict,
+                  wave: int | None = None) -> None:
+    """Run tasks one by one, each under a named scope of its label (prefixed
+    ``w<wave>.`` inside a fused program), so the program's op metadata
+    names the task."""
+    prefix = "" if wave is None else f"w{wave}."
     for tid in tids:
         t = tdg.tasks[tid]
         try:
@@ -272,7 +277,14 @@ def _run_unrolled(tdg: TDG, tids: Sequence[int], env: dict) -> None:
         except KeyError as e:  # pragma: no cover - defensive
             raise KeyError(f"task {t.label()} reads unbound slot {e} "
                            f"(region inputs: {tdg.input_slots})") from None
-        _bind_outs(t, t.fn(*args), env)
+        with jax.named_scope(prefix + t.label()):
+            _bind_outs(t, t.fn(*args), env)
+
+
+def _class_scope(tdg: TDG, cls: WaveClass, batcher: str) -> str:
+    """Named scope of one fused class: ``w<wave>.<payload>.<batcher>``."""
+    fn = tdg.tasks[cls.tids[0]].fn
+    return f"w{cls.wave}.{getattr(fn, '__name__', 'task')}.{batcher}"
 
 
 def _run_fused_class(tdg: TDG, cls: WaveClass, env: dict, batcher: str,
@@ -393,19 +405,22 @@ def fused_tdg_as_function(tdg: TDG, outputs: Sequence[str] | None = None,
             for cls in classify_wave(tdg, wi, wave, sig_of, min_class_size):
                 cls = _decide_class(tdg, cls, resolved, spec_of)
                 if not cls.fused:
-                    _run_unrolled(tdg, cls.tids, env)
+                    _run_unrolled(tdg, cls.tids, env, wave=wi)
                     applied.append(cls)
                     continue
                 try:
-                    padded = _run_fused_class(tdg, cls, env, cls.batcher,
-                                              mesh=mesh)
+                    # One named scope over the stacking, the batched call
+                    # and the slicing: the program's op metadata carries it.
+                    with jax.named_scope(_class_scope(tdg, cls, cls.batcher)):
+                        padded = _run_fused_class(tdg, cls, env, cls.batcher,
+                                                  mesh=mesh)
                     applied.append(dataclasses.replace(cls, padded=padded))
                 except Exception:
                     # Payload not batchable (no vmap rule, data-dependent
                     # control flow, ...): this class only degrades to the
                     # unrolled form. A payload broken under tracing per se
                     # re-raises from here with its real error.
-                    _run_unrolled(tdg, cls.tids, env)
+                    _run_unrolled(tdg, cls.tids, env, wave=wi)
                     applied.append(dataclasses.replace(
                         cls, fused=False, batcher="unrolled",
                         reason="trace fallback: payload not batchable"))

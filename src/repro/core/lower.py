@@ -34,7 +34,6 @@ import dataclasses
 import functools
 import os
 import threading
-import time
 from typing import Any, Callable, Mapping, Sequence
 
 import jax
@@ -42,6 +41,7 @@ import jax
 from . import costmodel as _costmodel
 from . import fuse as _fuse
 from . import schedule as _schedule
+from . import spans as _spans
 # Canonical definition lives in costmodel (the consumer of the numbers);
 # re-exported here because this module captures it on every AotExecutable
 # and tests/serialize reach it as lower._capture_cost_analysis.
@@ -371,23 +371,24 @@ def aot_compile_tdg(
     specs = {k: jax.tree_util.tree_map(abstract_leaf, v)
              for k, v in buffers.items()}
     donate_slots = tuple(k for k in donate_slots if k in specs)
-    t0 = time.perf_counter()
-    if donate_slots:
-        def split_fn(donated: dict, kept: dict) -> dict:
-            return fn({**kept, **donated})
+    with _spans.span("taskgraph.warmup.trace") as trace_span:
+        if donate_slots:
+            def split_fn(donated: dict, kept: dict) -> dict:
+                return fn({**kept, **donated})
 
-        donated_specs = {k: specs[k] for k in donate_slots}
-        kept_specs = {k: v for k, v in specs.items() if k not in donated_specs}
-        lowered = jax.jit(split_fn, donate_argnums=0).lower(donated_specs,
-                                                            kept_specs)
-    else:
-        lowered = jax.jit(fn).lower(specs)
-    t1 = time.perf_counter()
-    compiled = lowered.compile()
-    t2 = time.perf_counter()
+            donated_specs = {k: specs[k] for k in donate_slots}
+            kept_specs = {k: v for k, v in specs.items()
+                          if k not in donated_specs}
+            lowered = jax.jit(split_fn, donate_argnums=0).lower(
+                donated_specs, kept_specs)
+        else:
+            lowered = jax.jit(fn).lower(specs)
+    with _spans.span("taskgraph.warmup.compile") as compile_span:
+        compiled = lowered.compile()
     return AotExecutable(compiled=compiled, input_specs=specs, fused=do_fuse,
                          donate_slots=donate_slots,
                          cost_analysis=_capture_cost_analysis(compiled),
-                         trace_seconds=t1 - t0, compile_seconds=t2 - t1,
+                         trace_seconds=trace_span.record.seconds,
+                         compile_seconds=compile_span.record.seconds,
                          mesh_fp=_shreplay.mesh_fingerprint(mesh),
                          plan=getattr(fn, "last_plan", None))

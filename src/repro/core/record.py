@@ -36,6 +36,7 @@ persists for cross-process no-retrace replay).
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 from typing import Any, Callable, Mapping
 
@@ -44,6 +45,7 @@ import jax
 from . import costmodel as _costmodel
 from . import lower as _lower
 from . import schedule as _schedule
+from . import spans as _spans
 from .tdg import TDG, Task, buffers_signature
 from ..kernels import registry as _kreg
 from ..sharding import replay as _shreplay
@@ -95,6 +97,16 @@ class GraphBuilder:
         return list(self._env) if self._env is not None else []
 
 
+@dataclasses.dataclass
+class _CacheEntry:
+    """One replay-cache entry: the executable, and the leaf counts of its
+    arguments and outputs once a replay has counted them (the attributes of
+    the ``taskgraph.replay`` span, counted once per entry)."""
+
+    fn: Callable
+    leaves: tuple[int, int] | None = None
+
+
 class TaskGraphRegion:
     """A taskgraph region: static-or-recorded TDG + replay cache."""
 
@@ -123,7 +135,7 @@ class TaskGraphRegion:
         self.recurrent = recurrent
         self.tdg: TDG | None = None
         self.static = False
-        self._replay_cache: dict[tuple, Callable] = {}
+        self._replay_cache: dict[tuple, _CacheEntry] = {}
         self.records = 0
         self.replays = 0
         with _registry_lock:
@@ -146,16 +158,18 @@ class TaskGraphRegion:
 
     def record(self, **buffers) -> dict:
         """First execution: run eagerly while recording (paper §4.3.2)."""
-        tdg = TDG(region=self.name)
-        env = dict(buffers)
-        self.build_fn(GraphBuilder(tdg, env, abstract=False), **buffers)
-        tdg.validate()
-        self.tdg = tdg
-        self.static = False
-        self.records += 1
-        out = {s: env[s] for s in (self.outputs or tdg.output_slots)}
-        if not self.nowait:
-            jax.block_until_ready(out)
+        with _spans.span("taskgraph.record", region=self.name) as sp:
+            tdg = TDG(region=self.name)
+            env = dict(buffers)
+            self.build_fn(GraphBuilder(tdg, env, abstract=False), **buffers)
+            tdg.validate()
+            self.tdg = tdg
+            self.static = False
+            self.records += 1
+            out = {s: env[s] for s in (self.outputs or tdg.output_slots)}
+            if not self.nowait:
+                jax.block_until_ready(out)
+            sp.set(tasks=tdg.num_tasks)
         return out
 
     # -- execution ------------------------------------------------------------
@@ -167,22 +181,33 @@ class TaskGraphRegion:
         # between replays re-lowers instead of serving a stale substrate.
         # The replay mesh resolves (and keys) the same way, so flipping
         # REPRO_MESH between replays re-lowers too.
-        mode = _kreg.resolved_mode()
-        mesh = _shreplay.resolve_mesh(self.mesh)
-        sig = (buffers_signature(buffers), mode,
-               _shreplay.mesh_fingerprint(mesh),
-               _costmodel.plan_key(self.batcher))
-        fn = self._replay_cache.get(sig)
-        with _kreg.kernel_mode_scope(mode):
-            if fn is None:
-                fn = _lower.lower_tdg(self.tdg, donate_slots=self.donate_slots,
-                                      outputs=self.outputs, fuse=self.fuse,
-                                      batcher=self.batcher, mesh=mesh)
-                self._replay_cache[sig] = fn
-            out = fn(buffers)
-        self.replays += 1
-        if not self.nowait:
-            jax.block_until_ready(out)
+        with _spans.span("taskgraph.replay", region=self.name) as sp:
+            with _spans.span("taskgraph.replay.key"):
+                mode = _kreg.resolved_mode()
+                mesh = _shreplay.resolve_mesh(self.mesh)
+                sig = (buffers_signature(buffers), mode,
+                       _shreplay.mesh_fingerprint(mesh),
+                       _costmodel.plan_key(self.batcher))
+                entry = self._replay_cache.get(sig)
+            with _kreg.kernel_mode_scope(mode):
+                if entry is None:
+                    with _spans.span("taskgraph.replay.lower"):
+                        _spans.count("taskgraph.replay.cache_miss")
+                        entry = _CacheEntry(_lower.lower_tdg(
+                            self.tdg, donate_slots=self.donate_slots,
+                            outputs=self.outputs, fuse=self.fuse,
+                            batcher=self.batcher, mesh=mesh))
+                    self._replay_cache[sig] = entry
+                with _spans.span("taskgraph.replay.dispatch"):
+                    out = entry.fn(buffers)
+            self.replays += 1
+            if entry.leaves is None:
+                entry.leaves = (len(jax.tree_util.tree_leaves(buffers)),
+                                len(jax.tree_util.tree_leaves(out)))
+            sp.set(args=entry.leaves[0], outputs=entry.leaves[1])
+            if not self.nowait:
+                with _spans.span("taskgraph.replay.wait"):
+                    jax.block_until_ready(out)
         return out
 
     def warmup(self, **buffers) -> _lower.AotExecutable:
@@ -201,15 +226,17 @@ class TaskGraphRegion:
                 "or record once before warming up")
         mode = _kreg.resolved_mode()
         mesh = _shreplay.resolve_mesh(self.mesh)
-        with _kreg.kernel_mode_scope(mode):
+        with _spans.span("taskgraph.warmup", region=self.name), \
+                _kreg.kernel_mode_scope(mode):
             aot = _lower.aot_compile_tdg(self.tdg, buffers,
                                          outputs=self.outputs,
                                          donate_slots=self.donate_slots,
                                          fuse=self.fuse, batcher=self.batcher,
                                          mesh=mesh)
-        self._replay_cache[(buffers_signature(buffers), mode,
-                            _shreplay.mesh_fingerprint(mesh),
-                            _costmodel.plan_key(self.batcher))] = aot
+        sig = (buffers_signature(buffers), mode,
+               _shreplay.mesh_fingerprint(mesh),
+               _costmodel.plan_key(self.batcher))
+        self._replay_cache[sig] = _CacheEntry(aot)
         return aot
 
     def __call__(self, **buffers) -> dict:
