@@ -29,8 +29,12 @@ one compiled executable via the global cache in ``lower.py``, so the
 source-location registry keys region *identity* (instance sequencing,
 stats) but no longer implies per-location recompilation. The per-region
 ``_replay_cache`` is keyed by ``(buffers_signature, resolved kernel
-mode)`` — flipping ``REPRO_KERNELS`` between replays re-lowers instead of
-returning a stale-substrate executable. ``warmup()`` AOT-compiles a
+mode, mesh fingerprint, plan key)`` — flipping ``REPRO_KERNELS`` between
+replays re-lowers instead of returning a stale-substrate executable. The
+buffers' signature is one flatten of the whole buffer dict, so the key
+costs one pass over the slots per replay; the counters
+``taskgraph.replay.cache_hit`` and ``taskgraph.replay.cache_miss`` say
+which replays found an executable. ``warmup()`` AOT-compiles a
 signature off the critical path (and is what ``serialize.save_executable``
 persists for cross-process no-retrace replay).
 """
@@ -198,6 +202,8 @@ class TaskGraphRegion:
                             outputs=self.outputs, fuse=self.fuse,
                             batcher=self.batcher, mesh=mesh))
                     self._replay_cache[sig] = entry
+                else:
+                    _spans.count("taskgraph.replay.cache_hit")
                 with _spans.span("taskgraph.replay.dispatch"):
                     out = entry.fn(buffers)
             self.replays += 1

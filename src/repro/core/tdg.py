@@ -276,12 +276,32 @@ def structure_signature(tdg: TDG, outputs: Sequence[str] | None = None
     return sig, slot_map, tuple(payloads)
 
 
+def _leaf_signature(leaf: Any) -> tuple:
+    # A jax.Array's ``shape`` and ``dtype`` each fetch its aval: fetch it once.
+    aval = getattr(leaf, "aval", None)
+    if aval is not None:
+        return aval.shape, aval.dtype
+    dtype = getattr(leaf, "dtype", None)
+    if dtype is None:
+        # A leaf without a dtype (a Python scalar) stands in by its type. The
+        # 1-tuple never equals a (shape, dtype) pair, where a bare type could:
+        # ``np.dtype("float64") == float`` holds.
+        return (type(leaf),)
+    return getattr(leaf, "shape", ()), dtype
+
+
 def buffers_signature(buffers: Mapping[str, Any]) -> tuple:
-    """Abstract signature of a buffer dict (for replay-cache keying)."""
+    """Abstract signature of a buffer dict (for replay-cache keying).
+
+    ``(treedef, per-leaf signatures)`` from one flatten of the whole dict:
+    the treedef holds the sorted slot names and each slot's structure, and
+    each leaf gives ``(shape, dtype)``, or ``(type,)`` without a dtype.
+    Arrays and ``ShapeDtypeStruct`` specs of the same slots, shapes and
+    dtypes give equal signatures; weak type and slot order are ignored.
+    """
     import jax
 
-    sig = []
-    for k in sorted(buffers):
-        leaves, treedef = jax.tree_util.tree_flatten(buffers[k])
-        sig.append((k, treedef, tuple((getattr(l, "shape", ()), str(getattr(l, "dtype", type(l)))) for l in leaves)))
-    return tuple(sig)
+    if type(buffers) is not dict:
+        buffers = dict(buffers)
+    leaves, treedef = jax.tree_util.tree_flatten(buffers)
+    return treedef, tuple(map(_leaf_signature, leaves))

@@ -1058,9 +1058,9 @@ class RegionServer:
         tenant = req.tenant
         if tenant.aot_key is None:
             return None
+        slots = self._aot_spec_slots(tenant)
         want = buffers_signature(
-            {k: v for k, v in req.buffers.items()
-             if k in self._aot_spec_slots(tenant)})
+            {k: v for k, v in req.buffers.items() if k in slots})
         if want != tenant.aot_sig:
             return None
         entry = self.pool.get(tenant.aot_key)
@@ -1080,8 +1080,11 @@ class RegionServer:
         return None
 
     def _aot_spec_slots(self, tenant: Tenant) -> tuple:
-        # aot_sig rows are (slot, treedef, leafspec): recover the slot set.
-        return tuple(row[0] for row in (tenant.aot_sig or ()))
+        # aot_sig is (treedef of the spec dict, leaf specs): the dict's keys
+        # are the slot set.
+        if tenant.aot_sig is None:
+            return ()
+        return tuple(tenant.aot_sig[0].node_data()[1])
 
     def _run_batched(self, group: list[_Request]) -> tuple[list, bool]:
         """Serve a coalesced group; returns ``(results, coalesced)``.

@@ -113,3 +113,66 @@ def test_schedule_summary():
     s = region.schedule_summary()
     assert s["tasks"] == 3 and s["waves"] == 3 and s["roots"] == 1
     assert s["dep_lookups_at_record"] > 0
+
+
+def _tiled_cholesky(nb):
+    def potrf(a):
+        return jnp.linalg.cholesky(a)
+
+    def trsm(l_kk, a):
+        return jax.scipy.linalg.solve_triangular(l_kk, a.T, lower=True).T
+
+    def syrk(a, l):
+        return a - l @ l.T
+
+    def gemm(a, l1, l2):
+        return a - l1 @ l2.T
+
+    @taskgraph(name=f"cholesky_key_{nb}")
+    def region(g, **tiles):
+        for k in range(nb):
+            g.task(potrf, ins=[f"A{k}_{k}"], outs=[f"L{k}_{k}"])
+            for i in range(k + 1, nb):
+                g.task(trsm, ins=[f"L{k}_{k}", f"A{i}_{k}"], outs=[f"L{i}_{k}"])
+            for i in range(k + 1, nb):
+                g.task(syrk, ins=[f"A{i}_{i}", f"L{i}_{k}"], outs=[f"A{i}_{i}"])
+                for j in range(k + 1, i):
+                    g.task(gemm, ins=[f"A{i}_{j}", f"L{i}_{k}", f"L{j}_{k}"],
+                           outs=[f"A{i}_{j}"])
+    return region
+
+
+def test_warm_replays_hit_the_cache_from_specs():
+    """Warmed up from ShapeDtypeStruct specs, replays on arrays hit the
+    replay cache; one tile in another dtype misses once."""
+    from repro.core import spans
+
+    nb, bs = 8, 8
+    n = nb * bs
+    m = np.random.default_rng(0).standard_normal((n, n)).astype(np.float32)
+    a = jnp.asarray(m @ m.T + n * np.eye(n, dtype=np.float32))
+    tiles = {f"A{i}_{j}": a[i * bs:(i + 1) * bs, j * bs:(j + 1) * bs]
+             for i in range(nb) for j in range(i + 1)}
+    assert len(tiles) == 36
+    region = _tiled_cholesky(nb)
+    region.record(**tiles)
+    region.warmup(**{k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                     for k, v in tiles.items()})
+
+    def count(name):
+        return spans.counters().get(f"taskgraph.replay.{name}", 0)
+
+    hits, misses = count("cache_hit"), count("cache_miss")
+    for _ in range(3):
+        out = region(**tiles)
+    assert count("cache_miss") == misses
+    assert count("cache_hit") == hits + 3
+    assert len(region._replay_cache) == 1
+    l = np.tril(np.block([[out[f"L{i}_{j}"] if j <= i else np.zeros((bs, bs))
+                           for j in range(nb)] for i in range(nb)]))
+    np.testing.assert_allclose(l @ l.T, np.asarray(a), rtol=1e-4, atol=1e-3)
+
+    region(**{**tiles, "A7_1": tiles["A7_1"].astype(jnp.bfloat16)})
+    assert count("cache_miss") == misses + 1
+    assert count("cache_hit") == hits + 3
+    assert len(region._replay_cache) == 2
