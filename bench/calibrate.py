@@ -117,6 +117,7 @@ def chat_sweep(config, mix, generator, rates, seconds, seed: int) -> None:
         print(json.dumps(dict(
             rate=rate, offered_tokens_per_s=offered, **e2e,
             ttft_p50_ms=counters["ttft_p50_ms"],
+            ttft_p95_ms=counters["ttft_p95_ms"],
             itl_p50_ms=counters["itl_p50_ms"],
             first_token_after_close=len(late), requests=len(reqs),
             occupancy=(sm.occupancy_sum - o0) / max(sm.batches - b0, 1),

@@ -9,8 +9,9 @@ slots coalesce into one batched replay. A request that finds every slot
 busy waits in a FIFO queue, and its time to first token counts the wait.
 
 Set-up makes the weights from the seed and warms every program the window
-runs: a prefill per prompt length, the lone decode step and the batched
-step at every occupancy bucket up to the slot count.
+can run: a prefill per prompt length, the lone decode step and the batched
+step at every occupancy bucket up to the most requests that can decode at
+once (the window's request count, at most the slot count).
 """
 from __future__ import annotations
 
@@ -117,9 +118,10 @@ class Chat:
             tok = out["next"]
             return tok, int(tok[0]), out["caches"], pos + 1
 
-    def warm(self, log) -> None:
-        """Run every program the window will: each prompt length's prefill
-        and decode step, then one coalesced step at every bucket."""
+    def warm(self, log, most: int | None = None) -> None:
+        """Run every program the window can: each prompt length's prefill
+        and decode step, then one coalesced step at every bucket up to
+        ``most`` members (the slot count by default)."""
         jnp = self._jax.numpy
         rng = np_rng(0, 1)
         state = None
@@ -129,8 +131,9 @@ class Chat:
             tok, _, caches, pos = self.first_token(prompt)
             tok, _, caches, pos = self.step(0, tok, pos, caches)
             state = (tok, pos, caches)
+        most = min(self.slots, most or self.slots)
         buckets = sorted({self.server.buckets.bucket_for(k)
-                          for k in range(2, self.slots + 1)})
+                          for k in range(2, most + 1)})
         tok, pos, caches = state
         for b in buckets:
             members = [(f"slot{i}", {
@@ -220,14 +223,17 @@ def _e2e(records: list, cfg: dict, t0: float, t1: float) -> tuple[dict, dict]:
             flops += work.qwen2_request_flops(
                 cfg, len(r.req.prompt), len(inside))
     window = t1 - t0
+    spans = [(r.times[0], r.times[-1]) for r in records if r.times]
     e2e = {"gen_tokens_per_s": tokens / window,
-           "itl_p95_ms": nearest_rank(itl, 95) * 1e3,
-           "ttft_p95_ms": nearest_rank(ttft, 95) * 1e3}
+           "itl_p95_ms": nearest_rank(itl, 95) * 1e3}
     counters = {"tokens": tokens, "prompt_tokens": prompt_tok,
                 "decode_tokens": decode_tok, "model_flops": flops,
                 "ttft_p50_ms": nearest_rank(ttft, 50) * 1e3,
+                "ttft_p95_ms": nearest_rank(ttft, 95) * 1e3,
                 "itl_p50_ms": nearest_rank(itl, 50) * 1e3,
                 "requests": len(records),
+                "decoding_max": max((sum(a <= s <= b for a, b in spans)
+                                     for s, _ in spans), default=0),
                 "finished": sum(r.finished for r in records)}
     return e2e, counters
 
@@ -282,19 +288,22 @@ def run(ctx: harness.RunContext) -> harness.RunResult:
                                  dtype=jax.numpy.dtype(cfg["weights_dtype"]))
     chat = Chat(cfg, mix, weights)
     _check_layout(chat, weights)
-    chat.warm(ctx.log)
+    chat.warm(ctx.log, most=len(requests))
     records = [Record(req=r, due=0.0) for r in requests]
     sm = chat.server.metrics
     before = (sm.batches, sm.occupancy_sum)
+    held = [_bytes_in_use(jax.devices())]
     with ctx.window() as win:
         loop = _serve(chat, records, win, ctx.seconds, ctx.log)
         after = (sm.batches, sm.occupancy_sum)
+        held.append(_bytes_in_use(jax.devices()))
     peak = harness.memory_peak_bytes(jax.devices())
     e2e, counters = _e2e(records, cfg, win.t0, win.t1)
     counters.update(loop)
     counters["batches"] = after[0] - before[0]
     counters["occupancy_sum"] = after[1] - before[1]
     counters["rows"] = counters["prompt_tokens"] + counters["decode_tokens"]
+    counters["bytes_in_use_open"], counters["bytes_in_use_close"] = held
     chat.close()
     del chat
     gc.collect()
@@ -314,6 +323,14 @@ def run(ctx: harness.RunContext) -> harness.RunResult:
         checks=[harness.Check("served_logit_gap", value,
                               cfg["limits"]["served_logit_gap"])],
         counters=counters, window=win, memory_peak_bytes=peak)
+
+
+def _bytes_in_use(devices) -> int:
+    """Bytes held now on the fullest device (the peak since start-up is
+    ``harness.memory_peak_bytes``; here it is set by the warm-up's largest
+    bucket)."""
+    return max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+               for d in devices)
 
 
 def _check_layout(chat: Chat, weights) -> None:

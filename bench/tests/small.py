@@ -17,19 +17,6 @@ PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 CHOL = "cholesky-n16384.tile512"
 CHAT = "qwen2.5-3b-bf16.chat"
 
-#: The chat cell is not in BENCHMARK.json until it is proven on the chip;
-#: its driver, generator and reference are tested here on a cell of their own.
-CHAT_ENTRIES = {
-    "configs": {"name": "qwen2.5-3b-bf16",
-                "file": "bench/configs/qwen2.5-3b-bf16.json"},
-    "workloads": {"name": CHAT, "config": "qwen2.5-3b-bf16",
-                  "traffic": "chat", "chips": 1},
-    "end_to_end": [
-        {"name": n, "unit": u, "workloads": [CHAT]}
-        for n, u in (("gen_tokens_per_s", "tokens/s"), ("itl_p95_ms", "ms"),
-                     ("ttft_p95_ms", "ms"))],
-}
-
 
 def load_run():
     spec = importlib.util.spec_from_file_location("bench_run_module",
@@ -65,10 +52,6 @@ def run_small(cell: str, cfg: dict, mix: dict, seed: int = 3,
 
     run = load_run()
     spec = harness.load_spec()
-    if cell == CHAT and CHAT not in [w["name"] for w in spec["workloads"]]:
-        spec["configs"].append(CHAT_ENTRIES["configs"])
-        spec["workloads"].append(CHAT_ENTRIES["workloads"])
-        spec["end_to_end"].extend(CHAT_ENTRIES["end_to_end"])
     args = types.SimpleNamespace(workload=cell, seed=seed, seconds=seconds,
                                  trace=trace)
     line, checks = run.execute(spec, args, CPU_DEVICE, PEAKS,

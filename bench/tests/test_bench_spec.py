@@ -57,6 +57,23 @@ def test_every_name_of_a_cell_is_found(cell):
     assert set(cfg["limits"])
 
 
+#: The end-to-end metrics each cell reports; a later served cell joins the
+#: chat cell's served metrics.
+CELL_METRICS = {
+    "cholesky-n16384.tile512": {"region_ms", "setup_s"},
+    "qwen2.5-3b-bf16.chat": {"gen_tokens_per_s", "itl_p95_ms", "setup_s"},
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_METRICS))
+def test_each_cell_reports_its_end_to_end_metrics(cell):
+    reported = {m["name"] for m in harness.metrics_of(SPEC, cell, "end_to_end")}
+    assert reported == CELL_METRICS[cell]
+    for m in SPEC["per_layer"]:
+        if cell in m.get("workloads", [cell]):
+            assert m["moves"] in reported, m["name"]
+
+
 def test_a_new_name_resolves_without_editing_a_file(tmp_path):
     """A later PR adds a metric, a mix and a generator as new files only."""
     (tmp_path / "metrics").mkdir()
