@@ -16,6 +16,7 @@ _ARCH_MODULES = {
     "whisper-small": "whisper_small",
     "hymba-1.5b": "hymba_1_5b",
     "chameleon-34b": "chameleon_34b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 ARCHS = tuple(_ARCH_MODULES)

@@ -37,6 +37,7 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0              # GLM partial rotary
+    attn_scale: float = 0.0                 # 0 -> head_dim ** -0.5
 
     # MLP
     mlp: Literal["swiglu", "gelu", "relu2"] = "swiglu"
@@ -46,6 +47,9 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0                       # per-expert hidden (0 -> d_ff)
     num_shared_experts: int = 0
+    shared_d_ff: int = 0                    # shared-expert hidden (0 -> expert_d_ff)
+    experts_held: int = 0                   # experts on this chip (0 -> all)
+    expert_offset: int = 0                  # first held expert's id
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
 
@@ -59,6 +63,10 @@ class ModelConfig:
 
     # hybrid (Hymba): SSM runs in parallel with attention inside each block
     hybrid_ssm: bool = False
+    # interleaved hybrid (Granite 4.0-H): each layer's mixer is either
+    # "mamba" (Mamba-2) or "attention"; every layer ends in the MoE/MLP.
+    # Empty: every layer is the family's homogeneous block.
+    layer_types: tuple = ()
 
     # encoder-decoder (Whisper): stub conv frontend supplies frame embeddings
     encoder_layers: int = 0
@@ -69,15 +77,18 @@ class ModelConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0             # applied per-block output
     logit_scale: float = 1.0
+    norm_eps: float = 1e-6                  # RMSNorm epsilon
 
     # numerics / lowering
     dtype: str = "bfloat16"                 # activation/compute dtype
     param_dtype: str = "float32"
     attn_q_chunk: int = 2048                # q-chunking of full attention
 
-    # ---- beyond-paper perf knobs (see EXPERIMENTS.md §Perf) ----
+    # ---- beyond-paper perf knobs (docs/architecture.md) ----
     moe_impl: str = "gspmd"                 # "gspmd" | "shard_map" (EP-local
-    #                                         dispatch + psum combine)
+    #                                         dispatch + psum combine) |
+    #                                         "dropless" (held experts, no
+    #                                         capacity: sort + ragged GEMMs)
     shard_kv_seq: bool = False              # decode: shard cache length over
     #                                         "model" (MHA-style archs)
     ssm_split_proj: bool = False            # separate z/xBC/dt projections
@@ -90,6 +101,18 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(f"{len(self.layer_types)} layer_types for "
+                                 f"{self.num_layers} layers")
+            bad = set(self.layer_types) - {"mamba", "attention"}
+            if bad:
+                raise ValueError(f"unknown layer types {sorted(bad)}")
+        if self.expert_offset + self.held_experts > self.num_experts:
+            raise ValueError("held experts run past the router's experts")
+        if self.held_experts < self.num_experts and self.moe_impl != "dropless":
+            raise ValueError("only the dropless expert layer holds a share")
 
     @property
     def compute_dtype(self):
@@ -113,6 +136,18 @@ class ModelConfig:
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
+    @property
+    def shared_ff(self) -> int:
+        return self.shared_d_ff or self.expert_d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def attention_scale(self) -> float:
+        return self.attn_scale or self.head_dim ** -0.5
+
     def mlp_params(self, d_ff: int) -> int:
         per = 3 if self.mlp == "swiglu" else 2
         return per * self.d_model * d_ff
@@ -128,39 +163,36 @@ class ModelConfig:
         conv = self.ssm_conv * (di + 2 * g * n)
         return in_proj + out_proj + conv + 2 * self.ssm_heads
 
-    def block_params(self) -> int:
-        """Parameters of one decoder block (norms excluded, negligible)."""
-        p = 0
-        if self.family == "ssm":
-            return self.ssm_params()
-        p += self.attn_params()
-        if self.hybrid_ssm:
-            p += self.ssm_params()
-        if self.num_experts:
-            p += self.num_experts * self.mlp_params(self.expert_d_ff)
-            p += self.num_shared_experts * self.mlp_params(self.expert_d_ff)
-            p += self.d_model * self.num_experts          # router
-        else:
-            p += self.mlp_params(self.d_ff)
-        return p
+    def _ffn_params(self, active: bool) -> int:
+        if not self.num_experts:
+            return self.mlp_params(self.d_ff)
+        routed = self.top_k if active else self.held_experts
+        return (routed * self.mlp_params(self.expert_d_ff)
+                + self.num_shared_experts * self.mlp_params(self.shared_ff)
+                + self.d_model * self.num_experts)          # router
 
-    def active_block_params(self) -> int:
-        p = 0
+    def _block_params(self, layer: int, active: bool) -> int:
+        if self.layer_types:
+            mixer = (self.ssm_params() if self.layer_types[layer] == "mamba"
+                     else self.attn_params())
+            return mixer + self._ffn_params(active)
         if self.family == "ssm":
             return self.ssm_params()
-        p += self.attn_params()
+        p = self.attn_params()
         if self.hybrid_ssm:
             p += self.ssm_params()
-        if self.num_experts:
-            p += (self.top_k + self.num_shared_experts) * self.mlp_params(self.expert_d_ff)
-            p += self.d_model * self.num_experts
-        else:
-            p += self.mlp_params(self.d_ff)
-        return p
+        return p + self._ffn_params(active)
+
+    def block_params(self, layer: int = 0) -> int:
+        """Parameters of one decoder block (norms excluded, negligible)."""
+        return self._block_params(layer, active=False)
+
+    def active_block_params(self, layer: int = 0) -> int:
+        return self._block_params(layer, active=True)
 
     def param_count(self) -> int:
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        body = self.num_layers * self.block_params()
+        body = sum(self.block_params(i) for i in range(self.num_layers))
         if self.encoder_layers:
             body += self.encoder_layers * (self.attn_params() + self.mlp_params(self.d_ff))
             body += self.num_layers * self.attn_params()  # cross-attention
@@ -168,7 +200,7 @@ class ModelConfig:
 
     def active_param_count(self) -> int:
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        body = self.num_layers * self.active_block_params()
+        body = sum(self.active_block_params(i) for i in range(self.num_layers))
         if self.encoder_layers:
             body += self.encoder_layers * (self.attn_params() + self.mlp_params(self.d_ff))
             body += self.num_layers * self.attn_params()
@@ -182,8 +214,15 @@ class ModelConfig:
         n = self.active_param_count()
         mult = 6.0 if training else 2.0
         flops = mult * n
+        if self.layer_types:
+            n_attn = self.layer_types.count("attention")
+            n_ssm = self.num_layers - n_attn
+        else:
+            n_attn = 0 if self.family == "ssm" else self.num_layers
+            n_ssm = (self.num_layers if self.family == "ssm" or self.hybrid_ssm
+                     else 0)
         # effective kv context seen per token
-        if self.family != "ssm":
+        if n_attn:
             if decode:
                 eff = seq_len
                 if self.attention == "sliding" and self.window:
@@ -198,10 +237,9 @@ class ModelConfig:
                     eff = min(eff, self.attn_chunk / 2)
             # qk^T and pv matmuls: 2 * 2 * H * hd * eff each fwd
             att = 4.0 * self.num_heads * self.head_dim * eff
-            flops += (mult / 2) * self.num_layers * att
-        if self.family == "ssm" or self.hybrid_ssm:
-            # SSD state update + readout per token ~ 6 * d_inner * N
-            flops += (mult / 2) * self.num_layers * 6.0 * self.ssm_inner * self.ssm_state
+            flops += (mult / 2) * n_attn * att
+        # SSD state update + readout per token ~ 6 * d_inner * N
+        flops += (mult / 2) * n_ssm * 6.0 * self.ssm_inner * self.ssm_state
         return flops
 
 
@@ -228,7 +266,8 @@ SHAPES: dict[str, ShapeConfig] = {
 
 
 def shape_applicable(config: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Whether an (arch, shape) cell is assigned (DESIGN.md §Arch-applicability)."""
+    """Whether an (arch, shape) cell is assigned: ``long_500k`` only where
+    every layer's attention is sub-quadratic."""
     if shape.name == "long_500k":
         subquadratic = (config.family == "ssm"
                         or config.hybrid_ssm
@@ -253,6 +292,10 @@ def reduced(config: ModelConfig, **overrides) -> ModelConfig:
         num_experts=min(config.num_experts, 4),
         top_k=min(config.top_k, 2),
         moe_d_ff=96 if config.num_experts else 0,
+        shared_d_ff=128 if config.shared_d_ff else 0,
+        layer_types=("mamba", "attention") if config.layer_types else (),
+        experts_held=0,
+        expert_offset=0,
         # drop-free capacity: keeps smoke tests deterministic across
         # different token counts (prefill vs teacher-forced forward)
         capacity_factor=float(max(4, config.num_experts and 4)),

@@ -4,8 +4,8 @@
 vocab=32001. Each block runs attention heads and SSM heads in PARALLEL on
 the same input and fuses (mean of per-path RMSNorm) — per the paper.
 Sliding-window attention (w=1024) on all layers (the released model keeps
-3 full-attention layers; we use SWA uniformly and note the deviation in
-DESIGN.md) -> sub-quadratic, long_500k applicable.
+3 full-attention layers; we use SWA uniformly, a deviation) ->
+sub-quadratic, long_500k applicable.
 """
 from .base import ModelConfig
 

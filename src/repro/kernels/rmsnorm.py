@@ -30,6 +30,11 @@ def _rmsnorm_res_kernel(x_ref, r_ref, w_ref, o_ref, *, eps: float):
                   * w_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
+#: Elements of one block: its f32 temporaries must fit the 16 MiB of
+#: scoped VMEM (256 rows of 4096, or 128 of a Mamba-2 d_inner of 8192).
+BLOCK_ELEMENTS = 1 << 20
+
+
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
             residual: jax.Array | None = None, *, block_rows: int = 256,
             interpret: bool = False) -> jax.Array:
@@ -37,7 +42,8 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
     d = x.shape[-1]
     xf = x.reshape(-1, d)
     n = xf.shape[0]
-    block_rows = min(block_rows, max(8, 1 << (n - 1).bit_length()))
+    block_rows = min(block_rows, max(8, BLOCK_ELEMENTS // d // 8 * 8),
+                     max(8, 1 << (n - 1).bit_length()))
     n_pad = math.ceil(n / block_rows) * block_rows
     pad = n_pad - n
     if pad:

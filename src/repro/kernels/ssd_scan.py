@@ -32,8 +32,8 @@ def _ssd_chunk_kernel(xs_ref, b_ref, c_ref, lda_ref,
     """One (batch*head, chunk) cell: intra-chunk output + local end-state.
 
     xs  : (Q, P)  dt * x
-    b,c : (Q, N)
-    lda : (Q, 1)  log dA = dt * A
+    b,c : (Q, N)  the head's group's B and C
+    lda : (1, Q)  log dA = dt * A, as a row
     out y      : (Q, P)   intra-chunk contribution
     out state  : (N, P)   chunk end-state (before inter-chunk recurrence)
     out cdecay : (1, 1)   total log-decay across the chunk
@@ -41,19 +41,19 @@ def _ssd_chunk_kernel(xs_ref, b_ref, c_ref, lda_ref,
     xs = xs_ref[0].astype(jnp.float32)
     b = b_ref[0].astype(jnp.float32)
     c = c_ref[0].astype(jnp.float32)
-    lda = lda_ref[0].astype(jnp.float32)          # (Q, 1)
+    lda = lda_ref[0].astype(jnp.float32)          # (1, Q)
     Q = xs.shape[0]
 
     li = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     # Mosaic has no cumsum: the inclusive prefix sum is a masked reduction
     # in f32 (exact adds, unlike a bf16-pass MXU matmul), taken once as a
-    # row and moved to a column through the diagonal (no relayout needed).
-    lda_b = jnp.broadcast_to(lda, (Q, Q))         # [i, j] = lda[i]
-    cums_row = jnp.sum(jnp.where(li <= lj, lda_b, 0.0), axis=0,
-                       keepdims=True)             # (1, Q) inclusive
-    cums = jnp.sum(jnp.where(li == lj, cums_row, 0.0), axis=1,
-                   keepdims=True)                 # (Q, 1) same values
+    # column and moved to a row through the diagonal (no relayout needed).
+    lda_b = jnp.broadcast_to(lda, (Q, Q))         # [i, j] = lda[j]
+    cums = jnp.sum(jnp.where(lj <= li, lda_b, 0.0), axis=1,
+                   keepdims=True)                 # (Q, 1) inclusive
+    cums_row = jnp.sum(jnp.where(li == lj, cums, 0.0), axis=0,
+                       keepdims=True)             # (1, Q) same values
     # decay(i<-j) = exp(cums[i] - cums[j]) for j <= i
     diff = cums - cums_row                        # (Q_i, Q_j)
     L = jnp.where(li >= lj, jnp.exp(diff), 0.0)
@@ -73,32 +73,38 @@ def _ssd_chunk_kernel(xs_ref, b_ref, c_ref, lda_ref,
     cdecay_ref[0] = total.astype(cdecay_ref.dtype)
 
 
-def ssd_intra_chunk(xs, b, c, lda, *, chunk: int, interpret: bool = False):
+def ssd_intra_chunk(xs, b, c, lda, *, chunk: int, heads_per_group: int = 1,
+                    interpret: bool = False):
     """Pallas-gridded intra-chunk pass.
 
-    xs: (BH, S, P); b, c: (BH, S, N); lda: (BH, S, 1). S % chunk == 0.
+    xs: (BH, S, P); b, c: (BG, S, N), one row per (batch, group), shared by
+    the ``heads_per_group`` consecutive heads of the group; lda:
+    (BH * nc, 1, chunk), each chunk's log-decays as a row. S % chunk == 0.
     Returns (y_intra (BH,S,P), state_local (BH,nc,N,P), cdecay (BH,nc,1,1)).
     """
     BH, S, P = xs.shape
     N = b.shape[-1]
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
+    rep = heads_per_group
 
     grid = (BH, nc)
     seq_map = lambda h, c_: (h, c_, 0)
+    group_map = lambda h, c_: (h // rep, c_, 0)
+    cell_map = lambda h, c_: (h * nc + c_, 0, 0)
     out = compat.pallas_call(
         _ssd_chunk_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, P), seq_map),
-            pl.BlockSpec((1, chunk, N), seq_map),
-            pl.BlockSpec((1, chunk, N), seq_map),
-            pl.BlockSpec((1, chunk, 1), seq_map),
+            pl.BlockSpec((1, chunk, N), group_map),
+            pl.BlockSpec((1, chunk, N), group_map),
+            pl.BlockSpec((1, 1, chunk), cell_map),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, P), seq_map),
-            pl.BlockSpec((1, N, P), lambda h, c_: (h * nc + c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda h, c_: (h * nc + c_, 0, 0)),
+            pl.BlockSpec((1, N, P), cell_map),
+            pl.BlockSpec((1, 1, 1), cell_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, P), jnp.float32),
@@ -136,18 +142,18 @@ def ssd(
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
 
-    # layout: (B, S, H, *) -> (B*H, S, *)
-    def to_bh(t, d):
-        return jnp.moveaxis(t, 2, 1).reshape(Bsz * H, S, d)
-
+    # layout: (B, S, H, P) -> (B*H, S, P); B and C stay one row per
+    # (batch, group), read by each of the group's heads in the kernel
     dtf = dt.astype(jnp.float32)
-    xs = to_bh(x.astype(jnp.float32) * dtf[..., None], P)
-    Bh = to_bh(jnp.repeat(Bm, rep, axis=2).astype(jnp.float32), N)
-    Ch = to_bh(jnp.repeat(Cm, rep, axis=2).astype(jnp.float32), N)
-    lda = to_bh((dtf * A[None, None, :])[..., None], 1)
+    xs = jnp.moveaxis(x.astype(jnp.float32) * dtf[..., None], 2, 1).reshape(
+        Bsz * H, S, P)
+    Bg = jnp.moveaxis(Bm.astype(jnp.float32), 2, 1).reshape(Bsz * G, S, N)
+    Cg = jnp.moveaxis(Cm.astype(jnp.float32), 2, 1).reshape(Bsz * G, S, N)
+    lda = jnp.moveaxis(dtf * A[None, None, :], 2, 1)          # (B, H, S)
 
     y_intra, state_local, cdecay = ssd_intra_chunk(
-        xs, Bh, Ch, lda, chunk=chunk, interpret=interpret)
+        xs, Bg, Cg, lda.reshape(Bsz * H * nc, 1, chunk), chunk=chunk,
+        heads_per_group=rep, interpret=interpret)
 
     # inter-chunk recurrence (tiny: nc sequential steps over (BH, N, P))
     h0 = (jnp.zeros((Bsz * H, N, P), jnp.float32) if init_state is None
@@ -163,13 +169,13 @@ def ssd(
 
     hT, h_prevs = jax.lax.scan(
         step, h0, (jnp.moveaxis(cd, 1, 0), jnp.moveaxis(state_local, 1, 0)))
-    h_prev = jnp.moveaxis(h_prevs, 0, 1)                # (BH, nc, N, P)
+    h_prev = h_prevs.reshape(nc, Bsz, G, rep, N, P)
 
     # cross-chunk output: y[i] += exp(cums[i]) * C[i] @ h_prev(chunk(i))
-    cums = jnp.cumsum(lda.reshape(Bsz * H, nc, chunk, 1), axis=2)
-    c_c = Ch.reshape(Bsz * H, nc, chunk, N)
-    y_inter = jnp.einsum("zcin,zcnp,zci->zcip", c_c, h_prev,
-                         jnp.exp(cums[..., 0]))
+    cums = jnp.cumsum(lda.reshape(Bsz, G, rep, nc, chunk), axis=-1)
+    c_c = Cg.reshape(Bsz, G, nc, chunk, N)
+    y_inter = jnp.einsum("bgcin,cbgrnp,bgrci->bgrcip", c_c, h_prev,
+                         jnp.exp(cums))
     y = y_intra + y_inter.reshape(Bsz * H, S, P)
 
     y = jnp.moveaxis(y.reshape(Bsz, H, S, P), 1, 2)     # (B, S, H, P)

@@ -193,6 +193,7 @@ def attention_apply(
         chunk = span if pattern == "chunked" else None
         out = ops.attention(q, k, v, causal=causal and kv_x is None,
                             window=window, chunk=chunk,
+                            scale=cfg.attn_scale or None,
                             q_chunk=cfg.attn_q_chunk)
     out = out.reshape(B, S, H * hd)
     return linear(p["wo"], out, cdt), cache
@@ -236,7 +237,8 @@ def _cached_attention(cfg, q, k_new, v_new, positions, cache, *,
 
     group = cfg.num_heads // Hkv
     qg = q.reshape(q.shape[0], q.shape[1], Hkv, group, hd).astype(jnp.float32)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck.astype(jnp.float32)) * (hd ** -0.5)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
+                   ck.astype(jnp.float32)) * cfg.attention_scale
     qpos = positions[:, :, None]                            # (B, S, 1)
     kpos = cpos[:, None, :]                                 # (B, 1, L)
     mask = (kpos >= 0) & (kpos <= qpos)                     # filled & causal

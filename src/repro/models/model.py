@@ -28,8 +28,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     p: Params = {
         "embed": L.embedding_init(L.key_for(key, "embed"), cfg.padded_vocab,
                                   cfg.d_model, jnp.dtype(cfg.param_dtype)),
-        "layers": T.stack_init(L.key_for(key, "layers"), cfg, cfg.num_layers,
-                               T.block_init),
+        "layers": T.layers_init(L.key_for(key, "layers"), cfg),
         "final_norm": T._norm_init(cfg, cfg.d_model),
     }
     if not cfg.tie_embeddings:
@@ -153,10 +152,12 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: dict):
 # ---------------------------------------------------------------------------
 
 def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> list:
+    """Per-layer decode state: SSD and conv state on a Mamba-2 mixer, a K/V
+    ring on an attention layer (both on Hymba's parallel blocks)."""
     caches = []
     for i in range(cfg.num_layers):
         c: dict[str, Any] = {}
-        if cfg.family == "ssm":
+        if cfg.family == "ssm" or cfg.layer_types[i:i + 1] == ("mamba",):
             c["ssm"] = S.init_ssm_state(cfg, batch)
         else:
             c["attn"] = L.init_attn_cache(cfg, i, batch, max_len)

@@ -6,6 +6,13 @@ fixed wave of per-expert tasks in the TDG — the scheduler round-robins
 experts across the EP axis exactly like the paper round-robins root tasks
 across worker queues. Dropped tokens (over capacity) pass through the
 residual, standard for capacity-based MoE.
+
+``moe_impl="dropless"`` has no capacity and drops nothing: the layer holds
+experts ``[expert_offset, expert_offset + held_experts)`` of the router's
+``num_experts``, routes over all of them, and computes its own experts'
+share of the result (the rest lives on other chips of an expert-parallel
+deployment). Assignments to held experts are sorted by expert and run
+through ragged GEMMs (``jax.lax.ragged_dot``, a Mosaic kernel on TPU).
 """
 from __future__ import annotations
 
@@ -23,18 +30,21 @@ Params = dict
 
 
 def moe_init(key, cfg: ModelConfig) -> Params:
+    """Router over all ``num_experts``; weights of the held experts only."""
     d, f, E = cfg.d_model, cfg.expert_d_ff, cfg.num_experts
+    Eh = cfg.held_experts
     dt = jnp.dtype(cfg.param_dtype)
     p: Params = {
         "router": {"w": L._init_dense(L.key_for(key, "router"), (d, E), dt)},
         "experts": {
-            "up": {"w": L._init_dense(L.key_for(key, "eup"), (E, d, f), dt, 1)},
-            "gate": {"w": L._init_dense(L.key_for(key, "egate"), (E, d, f), dt, 1)},
-            "down": {"w": L._init_dense(L.key_for(key, "edown"), (E, f, d), dt, 1)},
+            "up": {"w": L._init_dense(L.key_for(key, "eup"), (Eh, d, f), dt, 1)},
+            "gate": {"w": L._init_dense(L.key_for(key, "egate"), (Eh, d, f), dt, 1)},
+            "down": {"w": L._init_dense(L.key_for(key, "edown"), (Eh, f, d), dt, 1)},
         },
     }
     for i in range(cfg.num_shared_experts):
-        p[f"shared{i}"] = L.mlp_init(L.key_for(key, "shared", i), cfg, d_ff=f)
+        p[f"shared{i}"] = L.mlp_init(L.key_for(key, "shared", i), cfg,
+                                     d_ff=cfg.shared_ff)
     return p
 
 
@@ -46,6 +56,8 @@ def capacity(cfg: ModelConfig, n_tokens: int) -> int:
 def moe_apply(p: Params, cfg: ModelConfig, x: jax.Array
               ) -> tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (out, aux_loss). Dispatches on cfg.moe_impl."""
+    if cfg.moe_impl == "dropless":
+        return moe_apply_dropless(p, cfg, x)
     if cfg.moe_impl == "shard_map" and P_.active_mesh() is not None \
             and "model" in P_.active_mesh().axis_names:
         return moe_apply_shard_map(p, cfg, x)
@@ -58,8 +70,7 @@ def moe_apply_gspmd(p: Params, cfg: ModelConfig, x: jax.Array
 
     Correct everywhere, but at pod scale the global-index scatter forces the
     partitioner to all-gather the token stream per layer (measured: the
-    dominant collective term for 128-expert configs — see EXPERIMENTS.md
-    §Perf iteration 1)."""
+    dominant collective term for 128-expert configs)."""
     B, S, d = x.shape
     E, K = cfg.num_experts, cfg.top_k
     cdt = cfg.compute_dtype
@@ -115,6 +126,126 @@ def moe_apply_gspmd(p: Params, cfg: ModelConfig, x: jax.Array
         gathered.astype(jnp.float32) * weights[:, None], tok_ids, num_segments=T)
     out = combined.astype(cdt).reshape(B, S, d)
 
+    for i in range(cfg.num_shared_experts):
+        out = out + L.mlp_apply(p[f"shared{i}"], cfg, x)
+    return out, aux
+
+
+# ---------------------------------------------------------------------------
+# Drop-free held-expert layer
+# ---------------------------------------------------------------------------
+
+#: Prefill tokens routed per pass: bounds the sorted rows to 4096 * top_k.
+TOKEN_BLOCK = 4096
+
+
+def route(p: Params, cfg: ModelConfig, xt: jax.Array):
+    """Softmax over all experts, top-k, renormalised (= softmax over the
+    top-k logits). Returns (gates (T, K) f32, experts (T, K), probs)."""
+    logits = jax.lax.dot_general(
+        xt.astype(cfg.compute_dtype), p["router"]["w"].astype(cfg.compute_dtype),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, cfg.top_k)
+    return gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9), experts, probs
+
+
+def _held_share(xt, local, gates, w_up, w_gate, w_down):
+    """The held experts' part of each token's output, in f32.
+
+    xt (T, d); local (T, K) expert ids relative to the first held expert
+    (outside ``[0, E_held)``: another chip's expert); gates (T, K).
+    Every assignment to a held expert is computed: no capacity, no drops.
+    """
+    T, d = xt.shape
+    K = local.shape[1]
+    Eh = w_up.shape[0]
+    flat = local.reshape(-1)
+    held = (flat >= 0) & (flat < Eh)
+    group = jnp.where(held, flat, Eh)                 # others sort last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
+    rows = xt[order // K]                             # (T*K, d) by expert
+    dt = xt.dtype
+    up = jax.lax.ragged_dot(rows, w_up.astype(dt), sizes)
+    gate = jax.lax.ragged_dot(rows, w_gate.astype(dt), sizes)
+    h = (jax.nn.silu(gate.astype(jnp.float32))
+         * up.astype(jnp.float32)).astype(dt)
+    y = jax.lax.ragged_dot(h, w_down.astype(dt), sizes)
+    # rows past the last held group belong to no group: never read them
+    y = jnp.where((jnp.arange(T * K) < sizes.sum())[:, None], y, 0)
+    y = y[jnp.argsort(order)].reshape(T, K, d)        # back to (token, k)
+    w = jnp.where(held, gates.reshape(-1), 0.0).reshape(T, K)
+    return jnp.einsum("tkd,tk->td", y.astype(jnp.float32), w)
+
+
+@jax.custom_vjp
+def held_share(xt, local, gates, w_up, w_gate, w_down):
+    return _held_share_batched(xt, local, gates, w_up, w_gate, w_down)
+
+
+def _held_share_fwd(*args):
+    return _held_share_batched(*args), args
+
+
+def _held_share_bwd(args, g):
+    return jax.vjp(_held_share, *args)[1](g)
+
+
+held_share.defvjp(_held_share_fwd, _held_share_bwd)
+
+
+@jax.custom_batching.custom_vmap
+def _held_share_batched(xt, local, gates, w_up, w_gate, w_down):
+    return _held_share(xt, local, gates, w_up, w_gate, w_down)
+
+
+@_held_share_batched.def_vmap
+def _held_share_vmap(axis_size, in_batched, xt, local, gates, w_up, w_gate,
+                     w_down):
+    """Batched members (a coalesced server step) fold into one token axis,
+    so each held expert's weights are read once per step, not per member.
+    A plain ``jax.vmap`` of ``ragged_dot`` with a batched lhs and shared
+    weights is not implemented in JAX ("vmap over any dim but 0")."""
+    if any(in_batched[3:]):
+        axes = tuple(0 if b else None for b in in_batched)
+        return jax.vmap(_held_share, in_axes=axes)(
+            xt, local, gates, w_up, w_gate, w_down), True
+    xt, local, gates = (
+        a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+        for a, b in zip((xt, local, gates), in_batched[:3]))
+    m, T, d = xt.shape
+    out = _held_share_batched(xt.reshape(m * T, d), local.reshape(m * T, -1),
+                     gates.reshape(m * T, -1), w_up, w_gate, w_down)
+    return out.reshape(m, T, d), True
+
+
+def moe_apply_dropless(p: Params, cfg: ModelConfig, x: jax.Array
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Drop-free MoE over the held experts, plus the shared experts."""
+    B, S, d = x.shape
+    E = cfg.num_experts
+    cdt = cfg.compute_dtype
+    T = B * S
+    xt = x.reshape(T, d).astype(cdt)
+    with jax.named_scope("moe.route"):
+        gates, experts, probs = route(p, cfg, xt)
+        ce = jnp.mean(jax.nn.one_hot(experts[:, 0], E, dtype=jnp.float32), 0)
+        aux = E * jnp.sum(jnp.mean(probs, axis=0) * ce) * cfg.router_aux_weight
+        local = experts - cfg.expert_offset
+    ew = p["experts"]
+    weights = (ew["up"]["w"], ew["gate"]["w"], ew["down"]["w"])
+    with jax.named_scope("moe.experts"):
+        if T > TOKEN_BLOCK and T % TOKEN_BLOCK == 0:
+            n = T // TOKEN_BLOCK
+            out = jax.lax.map(
+                lambda a: held_share(*a, *weights),
+                (xt.reshape(n, TOKEN_BLOCK, d),
+                 local.reshape(n, TOKEN_BLOCK, -1),
+                 gates.reshape(n, TOKEN_BLOCK, -1))).reshape(T, d)
+        else:
+            out = held_share(xt, local, gates, *weights)
+    out = out.astype(cdt).reshape(B, S, d)
     for i in range(cfg.num_shared_experts):
         out = out + L.mlp_apply(p[f"shared{i}"], cfg, x)
     return out, aux

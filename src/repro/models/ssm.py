@@ -152,15 +152,16 @@ def ssm_apply(p: Params, cfg: ModelConfig, x: jax.Array,
         y = y.reshape(Bz, 1, dd["d_inner"]).astype(cdt)
         new_ssd = h
     else:
-        y, new_ssd = ops.ssd(xin, dt.astype(cdt), A,
-                             Bm.astype(cdt), Cm.astype(cdt),
-                             D=p["D"].astype(jnp.float32),
-                             init_state=init, chunk=cfg.ssm_chunk)
+        with jax.named_scope("mamba2.ssd"):
+            y, new_ssd = ops.ssd(xin, dt.astype(cdt), A,
+                                 Bm.astype(cdt), Cm.astype(cdt),
+                                 D=p["D"].astype(jnp.float32),
+                                 init_state=init, chunk=cfg.ssm_chunk)
         y = y.reshape(Bz, S, dd["d_inner"]).astype(cdt)
 
     # gated norm + out projection
     y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    y = L.rmsnorm(p["norm"], y.astype(cdt))
+    y = L.rmsnorm(p["norm"], y.astype(cdt), eps=cfg.norm_eps)
     out = L.linear(p["out_proj"], y, cdt)
     new_state = ({"conv": new_conv, "ssd": new_ssd.astype(jnp.float32)}
                  if state is not None else None)

@@ -7,6 +7,13 @@ One homogeneous ``block_init``/``block_apply`` per architecture family:
   hybrid    : pre-norm (attention ∥ SSM heads, fused) + pre-norm MLP (Hymba)
   encdec    : whisper encoder blocks (bidir) + decoder blocks w/ cross-attn
 
+A config with ``layer_types`` (Granite 4.0-H) interleaves two block kinds
+instead: ``"mamba"`` (pre-norm Mamba-2 mixer) and ``"attention"`` (pre-norm
+GQA), each followed by the pre-norm MoE/MLP. Its layers are a list of
+per-layer param dicts, each with only its own mixer's weights, and are
+never sliced out of a stack (a kernel's operand sliced from a stack is a
+copy of the layer's weights on every call).
+
 Train/prefill run the sequence path; decode runs the single-step path
 against per-layer caches. Layer params are stacked (leading L dim):
 ``lax.scan`` over layers for training (grouped by ``global_attn_every``
@@ -37,20 +44,24 @@ def _norm_init(cfg: ModelConfig, d: int):
 
 
 def _norm(cfg: ModelConfig, p, x):
-    return L.layernorm(p, x) if cfg.family == "encdec" else L.rmsnorm(p, x)
+    return (L.layernorm(p, x) if cfg.family == "encdec"
+            else L.rmsnorm(p, x, eps=cfg.norm_eps))
 
 
 # ---------------------------------------------------------------------------
 # Decoder block (all families)
 # ---------------------------------------------------------------------------
 
-def block_init(key, cfg: ModelConfig) -> Params:
+def block_init(key, cfg: ModelConfig, kind: str | None = None) -> Params:
     d = cfg.d_model
     p: Params = {"norm1": _norm_init(cfg, d)}
     if cfg.family == "ssm":
         p["ssm"] = S.ssm_init(L.key_for(key, "ssm"), cfg)
         return p
-    p["attn"] = L.attention_init(L.key_for(key, "attn"), cfg)
+    if kind == "mamba":
+        p["ssm"] = S.ssm_init(L.key_for(key, "ssm"), cfg)
+    else:
+        p["attn"] = L.attention_init(L.key_for(key, "attn"), cfg)
     if cfg.hybrid_ssm:
         p["ssm"] = S.ssm_init(L.key_for(key, "ssm"), cfg)
         p["attn_out_norm"] = L.rmsnorm_init(d, jnp.dtype(cfg.param_dtype))
@@ -76,19 +87,40 @@ def block_apply(
     mode: str = "train",                 # train | prefill | decode
     cache: dict | None = None,
     enc_out: jax.Array | None = None,
+    kind: str | None = None,             # interleaved stacks: the mixer
 ) -> tuple[jax.Array, jax.Array, dict | None]:
     aux = jnp.zeros((), jnp.float32)
     rs = cfg.residual_scale
     new_cache: dict | None = dict(cache) if cache is not None else None
 
     h = _norm(cfg, p["norm1"], x)
-    if cfg.family == "ssm":
-        y, st = S.ssm_apply(p["ssm"], cfg, h,
-                            state=cache["ssm"] if cache else None)
+    if cfg.family == "ssm" or kind == "mamba":
+        with jax.named_scope("mamba2"):
+            y, st = S.ssm_apply(p["ssm"], cfg, h,
+                                state=cache["ssm"] if cache else None)
         if new_cache is not None:
             new_cache["ssm"] = st
-        return x + rs * y, aux, new_cache
+        x = x + rs * y
+        if cfg.family == "ssm":
+            return x, aux, new_cache
+    else:
+        x = _attention_mixer(p, cfg, x, h, positions, layer_idx=layer_idx,
+                             mode=mode, cache=cache, new_cache=new_cache,
+                             enc_out=enc_out)
+    # second half: pre-norm MoE or MLP on the residual
+    h2 = _norm(cfg, p["norm2"], x)
+    if cfg.num_experts:
+        mlp_out, aux = M.moe_apply(p["moe"], cfg, h2)
+    else:
+        mlp_out = L.mlp_apply(p["mlp"], cfg, h2)
+    return x + rs * mlp_out, aux, new_cache
 
+
+def _attention_mixer(p, cfg, x, h, positions, *, layer_idx, mode, cache,
+                     new_cache, enc_out):
+    """Attention (with Hymba's parallel SSM heads, whisper's cross-attention)
+    on the normed ``h``; returns the residual stream after it."""
+    rs = cfg.residual_scale
     pattern, span = L.layer_attn_pattern(cfg, layer_idx)
     if mode == "decode":
         attn_out, ac = L.attention_apply(
@@ -127,13 +159,7 @@ def block_apply(
             if new_cache is not None:
                 new_cache["cross_kv"] = _make_cross_cache(p["cross"], cfg, enc_out)
         x = x + rs * c_out
-
-    h2 = _norm(cfg, p["norm2"], x)
-    if cfg.num_experts:
-        mlp_out, aux = M.moe_apply(p["moe"], cfg, h2)
-    else:
-        mlp_out = L.mlp_apply(p["mlp"], cfg, h2)
-    return x + rs * mlp_out, aux, new_cache
+    return x
 
 
 def _write_prefill_cache(cfg, pa, h, positions, cache):
@@ -212,6 +238,15 @@ def stack_init(key, cfg: ModelConfig, n_layers: int, init_fn) -> Params:
     return jax.vmap(lambda k: init_fn(k, cfg))(keys)
 
 
+def layers_init(key, cfg: ModelConfig) -> Params | list:
+    """The decoder's layer params: one stack, or a list with one block per
+    layer of an interleaved config."""
+    if not cfg.layer_types:
+        return stack_init(key, cfg, cfg.num_layers, block_init)
+    return [block_init(k, cfg, kind=kind) for k, kind in zip(
+        jax.random.split(key, cfg.num_layers), cfg.layer_types)]
+
+
 def _remat_wrap(cfg: ModelConfig, fn):
     if cfg.remat == "none":
         return fn
@@ -226,7 +261,8 @@ def decoder_stack(params_layers: Params, cfg: ModelConfig, x: jax.Array,
                   caches: list | None = None, enc_out: jax.Array | None = None):
     """Run all decoder blocks. Returns (x, total_aux, new_caches)."""
     n = cfg.num_layers
-    if cfg.scan_layers and caches is None and cfg.family != "encdec":
+    if (cfg.scan_layers and caches is None and cfg.family != "encdec"
+            and not cfg.layer_types):
         g = cfg.global_attn_every if cfg.global_attn_every else 1
         assert n % g == 0
 
@@ -253,12 +289,14 @@ def decoder_stack(params_layers: Params, cfg: ModelConfig, x: jax.Array,
     aux = jnp.zeros((), jnp.float32)
     new_caches = [] if caches is not None else None
     for i in range(n):
-        pi = jax.tree_util.tree_map(lambda a: a[i], params_layers)
+        kind = cfg.layer_types[i] if cfg.layer_types else None
+        pi = (params_layers[i] if kind else
+              jax.tree_util.tree_map(lambda a: a[i], params_layers))
         ci = caches[i] if caches is not None else None
 
-        def run_block(pi_, x_, pos_, ci_, enc_, _i=i):
+        def run_block(pi_, x_, pos_, ci_, enc_, _i=i, _kind=kind):
             return block_apply(pi_, cfg, x_, pos_, layer_idx=_i, mode=mode,
-                               cache=ci_, enc_out=enc_)
+                               cache=ci_, enc_out=enc_, kind=_kind)
 
         if cfg.remat != "none" and mode == "train":
             if cfg.remat == "dots":

@@ -1,6 +1,6 @@
 """Train/serve step construction.
 
-Two granularities, per DESIGN.md §4:
+Two granularities (docs/architecture.md):
 
 * ``make_train_step`` — the production path: one pure function
   (fwd + bwd + clip + AdamW), replay-compiled once and re-executed every

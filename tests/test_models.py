@@ -133,7 +133,7 @@ def test_shape_applicability_matrix():
             else:
                 assert ok
             cells += ok
-    assert cells == 32   # 10 archs x 4 shapes - 8 inapplicable long_500k
+    assert cells == 35   # 11 archs x 4 shapes - 9 inapplicable long_500k
 
 
 def test_sliding_window_cache_ring_buffer():
