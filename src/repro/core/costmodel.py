@@ -31,6 +31,10 @@ This module closes the loop with two decision engines:
     unroll_flops``): ``unrolled`` — for near-free bodies the stack/unstack
     machinery costs more than just inlining the handful of ops.
 
+  The model also holds the cut break-even ``split_call_bytes``: the
+  stacking copy a fused call must save before ``fuse`` cuts a class into
+  sub-classes on a repeated argument.
+
   Unmeasurable payloads (no ``cost_analysis`` on this backend, probe
   failure, or XLA's ``-1`` "unknown flops" sentinel — CPU triangular solve
   reports this) fall back to ``vmap``, the static heuristic this model
@@ -79,6 +83,13 @@ DEFAULT_MAP_TOTAL_BYTES = 128 * 1024
 #: Unrolled break-even: classes whose TOTAL measured flops fall below this
 #: are cheaper inlined than stacked/unstacked.
 DEFAULT_UNROLL_FLOPS = 256.0
+#: Cut break-even (``fuse._split``): bytes of stacking copy a fused call
+#: must save to be worth adding, when a class is cut into sub-classes on a
+#: repeated argument. A TPU v5e streams 256 KiB in about 0.3 us (819
+#: GB/s), about what one more op in a program costs it: tiled linear
+#: algebra (tiles of 64 KiB and up) is cut, a class of tiny members with
+#: many distinct repeated slots stays whole.
+DEFAULT_SPLIT_CALL_BYTES = 256 * 1024
 
 
 def adaptive_enabled(arg: bool | str = "auto") -> bool:
@@ -170,11 +181,13 @@ class CostModel:
                  map_member_bytes: int = DEFAULT_MAP_MEMBER_BYTES,
                  map_total_bytes: int = DEFAULT_MAP_TOTAL_BYTES,
                  unroll_flops: float = DEFAULT_UNROLL_FLOPS,
+                 split_call_bytes: int = DEFAULT_SPLIT_CALL_BYTES,
                  cache_size: int = 512):
         self.ridge = float(ridge)
         self.map_member_bytes = int(map_member_bytes)
         self.map_total_bytes = int(map_total_bytes)
         self.unroll_flops = float(unroll_flops)
+        self.split_call_bytes = int(split_call_bytes)
         self._lock = threading.Lock()
         self._cache_size = max(1, int(cache_size))
         # key -> (payload strong ref, ClassCost)
@@ -186,7 +199,8 @@ class CostModel:
     def fingerprint(self) -> str:
         """Threshold fingerprint — part of the adaptive plan's cache key."""
         return (f"r{self.ridge:g}-m{self.map_member_bytes}"
-                f"-t{self.map_total_bytes}-u{self.unroll_flops:g}")
+                f"-t{self.map_total_bytes}-u{self.unroll_flops:g}"
+                f"-c{self.split_call_bytes}")
 
     # -- measurement -------------------------------------------------------
     def measure(self, fn: Callable, arg_specs: Sequence[Any]) -> ClassCost:
@@ -333,12 +347,13 @@ def plan_key(batcher: str) -> str:
     adaptive, or adaptive under different thresholds) must never share an
     executable: the decisions are baked into the trace. The adaptive key
     carries the model's threshold fingerprint so even a threshold change
-    re-lowers.
+    re-lowers. A static plan carries the cut break-even (``c<bytes>``),
+    which decides the classes under every batcher.
     """
     resolved = resolve_batcher(batcher)
     if resolved == "auto":
         return f"auto/{default_model().fingerprint()}"
-    return resolved
+    return f"{resolved}/c{default_model().split_call_bytes}"
 
 
 # ------------------------------------------------------------ bucket fitting

@@ -60,6 +60,7 @@ def structure_report(tdg: TDG, buffers: Mapping[str, Any],
             "map_member_bytes_max": model.map_member_bytes,
             "map_total_bytes_min": model.map_total_bytes,
             "unroll_flops_breakeven": model.unroll_flops,
+            "split_call_bytes_breakeven": model.split_call_bytes,
         },
         "tasks": summary["tasks"],
         "waves": summary["waves"],
@@ -111,7 +112,8 @@ def print_structure_report(rep: dict) -> None:
     print(f"   policy: intensity ridge {pol['ridge_flops_per_byte']:g} "
           f"flops/B | map member<= {pol['map_member_bytes_max']}B, "
           f"batch>= {pol['map_total_bytes_min']}B | unroll< "
-          f"{pol['unroll_flops_breakeven']:g} flops")
+          f"{pol['unroll_flops_breakeven']:g} flops | cut saves>= "
+          f"{pol['split_call_bytes_breakeven']}B a call")
     for d in rep["decisions"]:
         flops = "?" if d["flops"] is None else f"{d['flops']:g}"
         nbytes = "?" if d["bytes"] is None else f"{d['bytes']:g}"

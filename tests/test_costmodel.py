@@ -174,8 +174,9 @@ class TestProbe:
 
 class TestPlanKey:
     def test_static_plans_pass_through(self):
-        assert cm.plan_key("vmap") == "vmap"
-        assert cm.plan_key("map") == "map"
+        cut = cm.default_model().split_call_bytes
+        assert cm.plan_key("vmap") == f"vmap/c{cut}"
+        assert cm.plan_key("map") == f"map/c{cut}"
 
     def test_adaptive_plan_carries_threshold_fingerprint(self):
         key = cm.plan_key("auto")
@@ -184,7 +185,7 @@ class TestPlanKey:
     def test_kill_switch_collapses_auto_to_vmap(self, monkeypatch):
         monkeypatch.setenv(cm.ADAPTIVE_ENV, "0")
         assert cm.resolve_batcher("auto") == "vmap"
-        assert cm.plan_key("auto") == "vmap"
+        assert cm.plan_key("auto") == cm.plan_key("vmap")
         monkeypatch.setenv(cm.ADAPTIVE_ENV, "1")
         assert cm.resolve_batcher("auto") == "auto"
 
@@ -299,7 +300,8 @@ class TestAdaptivePlan:
         ex = ReplayExecutor(tdg, batcher="auto")
         assert ex.plan_key.startswith("auto/")
         monkeypatch.setenv(cm.ADAPTIVE_ENV, "0")
-        assert ReplayExecutor(tdg, batcher="auto").plan_key == "vmap"
+        assert ReplayExecutor(tdg, batcher="auto").plan_key == \
+            cm.plan_key("vmap")
 
 
 # ------------------------------------------------------- bucket boundaries

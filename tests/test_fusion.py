@@ -91,6 +91,41 @@ def _moe_tdg(n_tokens_blocks=6, dim=16):
     return tdg, bufs
 
 
+def _cholesky_tdg(nb, bs=128):
+    """Tiled Cholesky (potrf/trsm/syrk/gemm over the lower tiles): each
+    step's gemm reads ``L[j,k]`` once per ``i > j``, a repeated operand."""
+    def potrf(a):
+        return jnp.linalg.cholesky(a)
+
+    def trsm(l_kk, a):
+        return jax.scipy.linalg.solve_triangular(l_kk, a.T, lower=True).T
+
+    def syrk(a, l):
+        return a - l @ l.T
+
+    def gemm(a, l1, l2):
+        return a - l1 @ l2.T
+
+    tdg = TDG(f"cholesky{nb}x{bs}")
+    for k in range(nb):
+        tdg.add_task(potrf, ins=[f"A{k}_{k}"], outs=[f"L{k}_{k}"])
+        for i in range(k + 1, nb):
+            tdg.add_task(trsm, ins=[f"L{k}_{k}", f"A{i}_{k}"],
+                         outs=[f"L{i}_{k}"])
+        for i in range(k + 1, nb):
+            tdg.add_task(syrk, ins=[f"A{i}_{i}", f"L{i}_{k}"],
+                         outs=[f"A{i}_{i}"])
+            for j in range(k + 1, i):
+                tdg.add_task(gemm, ins=[f"A{i}_{j}", f"L{i}_{k}", f"L{j}_{k}"],
+                             outs=[f"A{i}_{j}"])
+    n = nb * bs
+    m = np.random.default_rng(nb).standard_normal((n, n))
+    a = (m @ m.T + n * np.eye(n)).astype(np.float32)
+    bufs = {f"A{i}_{j}": jnp.asarray(a[i * bs:(i + 1) * bs, j * bs:(j + 1) * bs])
+            for i in range(nb) for j in range(i + 1)}
+    return tdg, bufs
+
+
 GRAPHS = {
     "grid": _grid_tdg,
     "chain": _chain_tdg,
@@ -202,6 +237,168 @@ class TestWaveAnalysis:
         assert len(calls) == 1
         for t in range(5):
             np.testing.assert_allclose(out[f"y{t}"], jnp.arange(3.0) + 1)
+
+
+class TestStackedHandOff:
+    """A fused class's stacked result is read back as a view (whole or a
+    slice) by later classes, and a repeated operand is broadcast in
+    sub-classes instead of stacked once per member."""
+
+    @pytest.mark.parametrize("nb", [4, 6, 8])
+    def test_tiled_cholesky_fused_vs_unfused_vs_eager(self, nb):
+        tdg, bufs = _cholesky_tdg(nb)
+        with jax.default_matmul_precision("highest"):
+            eager = EagerExecutor(tdg, n_workers=3).run(dict(bufs))
+            unfused = lower_tdg(tdg, fuse=False, intern=False)(dict(bufs))
+            fused = lower_tdg(tdg, fuse=True, intern=False)(dict(bufs))
+        assert set(eager) == set(unfused) == set(fused)
+        for k in fused:
+            for ref in (unfused, eager):
+                np.testing.assert_allclose(np.asarray(fused[k]),
+                                           np.asarray(ref[k]),
+                                           rtol=1e-5, atol=1e-4, err_msg=k)
+
+    def test_cholesky_gemm_cut_on_repeated_operand(self):
+        nb = 8
+        tdg, bufs = _cholesky_tdg(nb)
+        plan = fusion_plan(tdg, bufs)
+        gemm = [c for c in plan.classes
+                if tdg.tasks[c.tids[0]].fn.__name__ == "gemm"]
+        # steps 0..nb-4 have >= 2 distinct L[j,k]: each is cut by j, the
+        # third argument, which every sub-class broadcasts
+        assert plan.split_classes == nb - 3
+        cut = [c for c in gemm if c.wave < 3 * (nb - 3)]
+        assert [c.wave for c in gemm if c not in cut] == [3 * (nb - 3) + 2]
+        for c in cut:
+            assert c.split is not None and c.split[0] == 2
+            assert len({tdg.tasks[t].ins[2] for t in c.tids}) == 1
+            if c.size > 1:
+                assert c.shared == (False, False, True)
+        assert plan.split_off == sum(len(range(k + 1, nb - 1)) - 1
+                                     for k in range(nb - 3))
+        # past step 0 every fused gemm reads A whole, L[i,k] as a slice
+        for c in gemm:
+            if c.fused and c.wave > 2:
+                assert c.args == ("whole", "slice", "broadcast")
+                assert c.stacked_bytes == 0
+
+    def test_cholesky_stacks_only_region_inputs(self):
+        """In the program, ``concatenate`` (a stacked copy) appears only
+        where step 0 reads the region's input tiles: no hand-off between
+        waves copies."""
+        import re
+
+        tdg, bufs = _cholesky_tdg(8)
+        f = lower_tdg(tdg, jit=False, intern=False)
+        text = jax.jit(f).lower(bufs).as_text()
+        plan = f.last_plan
+        stacked = [c for c in plan.classes if "stacked" in c.args]
+        assert stacked and all(c.wave <= 2 for c in stacked)   # step 0
+        inputs = set(tdg.input_slots)
+        for c in stacked:
+            for i, kind in enumerate(c.args):
+                if kind == "stacked":
+                    assert {tdg.tasks[t].ins[i] for t in c.tids} <= inputs
+        n_stacked = sum(c.args.count("stacked") for c in plan.classes)
+        assert len(re.findall(r"stablehlo\.concatenate", text)) == n_stacked
+
+    def test_plan_counters_agree_with_trace(self):
+        from repro.core import spans
+
+        tdg, bufs = _cholesky_tdg(6)
+        offline = fusion_plan(tdg, bufs).summary()
+        before = spans.counters()
+        f = lower_tdg(tdg, jit=False, intern=False)
+        jax.eval_shape(f, bufs)
+        after = spans.counters()
+        summary = f.last_plan.summary()
+
+        def delta(name):
+            return after.get(name, 0) - before.get(name, 0)
+
+        for k, n in summary["args"].items():
+            assert delta(f"taskgraph.fuse.arg_{k}") == n
+        assert delta("taskgraph.fuse.split_off") == summary["split_off"] > 0
+        assert delta("taskgraph.fuse.stacked_bytes") == \
+            summary["stacked_bytes"] > 0
+        offline.pop("decisions"), summary.pop("decisions")
+        assert offline == summary
+
+    def test_tiled_matmul_cuts_every_step_and_hands_c_on_whole(self):
+        """``C[i,j] += A[i,k] B[k,j]``: cutting step k by ``A[i,k]`` pays
+        only if step k+1 is cut the same way, which the look ahead at the
+        readers' own cuts sees; every step is cut and ``C`` is handed on
+        whole."""
+        def mac(c, a, b):
+            return c + a @ b
+
+        nb, bs = 3, 128
+        tdg = TDG("matmul")
+        for k in range(nb):
+            for i in range(nb):
+                for j in range(nb):
+                    tdg.add_task(mac, ins=[f"C{i}{j}", f"A{i}{k}", f"B{k}{j}"],
+                                 outs=[f"C{i}{j}"])
+        rng = np.random.default_rng(4)
+        bufs = {f"{p}{i}{j}": jnp.asarray(rng.standard_normal((bs, bs)),
+                                          jnp.float32)
+                for p in "ABC" for i in range(nb) for j in range(nb)}
+        plan = fusion_plan(tdg, bufs)
+        assert plan.split_classes == nb and plan.split_off == nb * (nb - 1)
+        for c in plan.classes:
+            assert c.split[0] == 1 and c.args[1] == "broadcast"
+            assert c.args[0] == ("stacked" if c.wave == 0 else "whole")
+        with jax.default_matmul_precision("highest"):
+            fused = lower_tdg(tdg, intern=False)(dict(bufs))
+            unfused = lower_tdg(tdg, fuse=False, intern=False)(dict(bufs))
+        for k in fused:
+            np.testing.assert_allclose(np.asarray(fused[k]),
+                                       np.asarray(unfused[k]),
+                                       rtol=1e-5, atol=1e-4, err_msg=k)
+
+    def test_tiny_repeated_operand_stays_whole(self):
+        """8x8 tiles: cutting would add calls worth more than the copies
+        it saves (the cost model's ``split_call_bytes``), so the plan is as
+        it was."""
+        tdg, bufs = _cholesky_tdg(6, bs=8)
+        plan = fusion_plan(tdg, bufs)
+        assert plan.split_off == plan.split_classes == 0
+        assert all(c.split is None for c in plan.classes)
+
+    def test_cut_break_even_comes_from_the_cost_model(self, monkeypatch):
+        """The cut's per-call charge is the process cost model's, and the
+        plan key carries it: with no charge the 8x8 tiles are cut too."""
+        from repro.core import costmodel
+
+        tdg, bufs = _cholesky_tdg(6, bs=8)
+        key = costmodel.plan_key("vmap")
+        monkeypatch.setattr(costmodel.default_model(), "split_call_bytes", 0)
+        assert costmodel.plan_key("vmap") != key
+        plan = fusion_plan(tdg, bufs)
+        assert plan.split_classes == 6 - 3
+        with jax.default_matmul_precision("highest"):
+            fused = lower_tdg(tdg, intern=False)(dict(bufs))
+            unfused = lower_tdg(tdg, fuse=False, intern=False)(dict(bufs))
+        for k in fused:
+            np.testing.assert_allclose(np.asarray(fused[k]),
+                                       np.asarray(unfused[k]),
+                                       rtol=1e-5, atol=1e-4, err_msg=k)
+
+    @pytest.mark.parametrize("graph", sorted(GRAPHS))
+    def test_graphs_without_repeated_operand_keep_their_classes(self, graph):
+        tdg, bufs = GRAPHS[graph]()
+        plan = fusion_plan(tdg, bufs)
+        structural = [c.tids for wi, wave in enumerate(topo_waves(tdg))
+                      for c in classify_wave(tdg, wi, wave, None)]
+        assert [c.tids for c in plan.classes] == structural
+        assert plan.split_off == 0
+
+    def test_isomorphic_waves_hand_off_whole_stacks(self):
+        tdg, bufs = _grid_tdg(n_waves=4, n_tasks=6)
+        plan = fusion_plan(tdg, bufs)
+        assert [c.args for c in plan.classes] == \
+            [("stacked",)] + [("whole",)] * 3
+        assert plan.stacked_bytes == 6 * 8 * 8 * 4     # the inputs, once
 
 
 class TestJaxprSize:

@@ -339,6 +339,37 @@ class TestDifferential:
                            lower_tdg(tdg, mesh=None)(buffers))
 
     @needs(2)
+    def test_repeated_operand_class_stays_whole_under_mesh(self):
+        """``x[i] @ w[j]`` over all pairs: ``w[j]`` repeats across members.
+        One device cuts the class by ``j`` and broadcasts ``w[j]``; under a
+        mesh the class stays whole and every argument is a stacked copy,
+        and the two replays agree."""
+        def pair(x, w):
+            return jnp.tanh(x @ w) + x
+
+        n = 4
+        tdg = TDG(region="mesh_repeated")
+        for i in range(n):
+            for j in range(n):
+                tdg.add_task(pair, ins=[f"x{i}", f"w{j}"], outs=[f"y{i}_{j}"])
+        rng = np.random.default_rng(5)
+        buffers = {f"{p}{i}": jnp.asarray(rng.standard_normal((128, 128)),
+                                          jnp.float32)
+                   for p in "xw" for i in range(n)}
+        mesh = make_replay_mesh(_largest_mesh())
+
+        plain = fused_tdg_as_function(tdg)
+        plain_out = plain(buffers)
+        assert plain.last_plan.split_off == n - 1
+        sharded = fused_tdg_as_function(tdg, mesh=mesh)
+        sharded_out = sharded(buffers)
+        [cls] = sharded.last_plan.classes
+        assert cls.split is None and cls.args == ("stacked", "stacked")
+        _assert_tree_close(sharded_out, plain_out, tol=1e-5)
+        _assert_tree_close(lower_tdg(tdg, mesh=mesh)(buffers),
+                           lower_tdg(tdg, mesh=None)(buffers), tol=1e-5)
+
+    @needs(2)
     def test_map_batcher_ignores_mesh(self):
         """lax.map serializes class members on purpose — it must stay
         single-device (mesh silently dropped), and still agree."""
